@@ -15,6 +15,13 @@ Pins the contract of the data-parallel layer on forced-CPU hardware:
   bitwise identical between dp=1 (4 shards folded on one device) and dp=4
   (1 shard per device).
 
+Everything here runs on the CPU. The parity child is started after this
+process has already initialized JAX, so it forces ``JAX_PLATFORMS=cpu``:
+on a machine with TPUs it still sees only four host devices, never the
+chips (the parent holds them). This checks the partitioned program's
+semantics, not the chips; the four-chip check is
+``python chip_smoke.py --four-chips``, in one process.
+
 ``--ci`` turns any violation into a failing exit code.
 
     PYTHONPATH=src python -m benchmarks.dist_smoke --ci
@@ -108,8 +115,9 @@ def _quiet(*_a, **_k):
 
 
 def _run_dp4_subprocess() -> dict:
-    """Run the parity/HLO check under 4 forced host devices; returns the
-    JSON result dict printed by the child."""
+    """Run the parity/HLO check under 4 forced host CPU devices (never the
+    chips: see the module docstring); returns the JSON result dict printed
+    by the child."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=4").strip()

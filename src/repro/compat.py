@@ -1,69 +1,22 @@
-"""Version-compat shims for the installed jax.
+"""One import point for the jax APIs the model and kernel code share.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` to the top-level
-namespace around jax 0.4.34, and its replication-check kwarg was renamed
-``check_rep`` -> ``check_vma``. Model code imports ``shard_map`` from here
-and always passes ``check_vma=...``; the wrapper renames the kwarg when the
-installed jax still uses the old spelling.
-
-``segment_sum`` / ``segment_max``: the ``jax.ops`` namespace is deprecated
-and slated for removal; kernel/reference code imports the segment reductions
-from here. When ``jax.ops`` still provides them we use it, otherwise we fall
-back to the equivalent ``jax.lax`` scatter ops (``.at[].add`` / ``.at[].max``
-lower to ``lax.scatter_add`` / ``lax.scatter_max``).
+The repository targets the installed toolchain only (jax/jaxlib 0.9.0,
+pinned in ``requirements.txt``): ``shard_map`` comes from the top-level
+namespace and takes ``check_vma``; the segment reductions come from
+``jax.ops``.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-import jax.numpy as jnp
 
-try:  # jax >= 0.4.34 exports shard_map at top level
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # older jaxlib: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map_impl).parameters
-    else "check_rep"
-)
+shard_map = jax.shard_map
 
 
-def shard_map(f, **kw):
-    """shard_map with the check kwarg renamed for the installed jax."""
-    if "check_vma" in kw and _CHECK_KW != "check_vma":
-        kw[_CHECK_KW] = kw.pop("check_vma")
-    return _shard_map_impl(f, **kw)
+def segment_sum(data, segment_ids, num_segments):
+    """sum of ``data`` rows per segment id -> [num_segments, ...]."""
+    return jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
 
 
-# ---------------------------------------------------------------------------
-# segment reductions (jax.ops is deprecated; fall back to lax scatter ops)
-# ---------------------------------------------------------------------------
-def _segment_sum_scatter(data, segment_ids, num_segments):
-    shape = (num_segments,) + data.shape[1:]
-    return jnp.zeros(shape, data.dtype).at[segment_ids].add(data)
-
-
-def _segment_max_scatter(data, segment_ids, num_segments):
-    shape = (num_segments,) + data.shape[1:]
-    init = jnp.full(shape, -jnp.inf, data.dtype)
-    return init.at[segment_ids].max(data)
-
-
-if hasattr(getattr(jax, "ops", None), "segment_sum"):
-    def segment_sum(data, segment_ids, num_segments):
-        """sum of ``data`` rows per segment id -> [num_segments, ...]."""
-        return jax.ops.segment_sum(data, segment_ids,
-                                   num_segments=num_segments)
-else:  # pragma: no cover - exercised only on jax without jax.ops
-    segment_sum = _segment_sum_scatter
-
-if hasattr(getattr(jax, "ops", None), "segment_max"):
-    def segment_max(data, segment_ids, num_segments):
-        """max of ``data`` rows per segment id; empty segments -> -inf."""
-        return jax.ops.segment_max(data, segment_ids,
-                                   num_segments=num_segments)
-else:  # pragma: no cover - exercised only on jax without jax.ops
-    segment_max = _segment_max_scatter
+def segment_max(data, segment_ids, num_segments):
+    """max of ``data`` rows per segment id; empty segments -> -inf."""
+    return jax.ops.segment_max(data, segment_ids, num_segments=num_segments)

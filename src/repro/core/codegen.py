@@ -311,16 +311,22 @@ _FUSABLE_GATHERS = (O.GatherScheme.BY_EDGE_SRC, O.GatherScheme.BY_EDGE_DST,
                     O.GatherScheme.BY_UNIQUE_SRC)
 
 
-def _fits_vmem(arr, *index_arrays) -> bool:
-    """Default gather-fusion heuristic: the ungathered source block PLUS the
-    scalar-prefetched gather/slot-map index arrays must all stay resident in
-    VMEM, inside the budget derived from the device's actual VMEM size
-    (``tune/device.py``; overridable via env)."""
-    total = arr.size * arr.dtype.itemsize
-    for ix in index_arrays:
-        if ix is not None:
-            total += ix.size * ix.dtype.itemsize
-    return total <= tunedev.fused_gather_budget_bytes()
+def _gather_fits(src, index_map=None, tile: int = 1) -> bool:
+    """Gather-fusion gate: the ungathered source block must fit the VMEM
+    budget and the scalar-prefetched slot map (+ per-tile table) SMEM,
+    each against the capacity of the device that runs the kernel
+    (``tune/device.py``)."""
+    slots = 0 if index_map is None else int(index_map.size)
+    return tunedev.fused_gather_fits(src.shape[0], src.shape[-1],
+                                     src.dtype.itemsize, slots, tile)
+
+
+def _fuse(dec, fits: bool) -> bool:
+    """A tuned decision may turn gather fusion off, never on past the
+    gate: a kernel that does not fit is never handed to the compiler."""
+    if dec is not None and dec.fuse_gather is not None:
+        return dec.fuse_gather and fits
+    return fits
 
 
 def _gemm_decision(decisions, op, lay, x_src, w, has_scale):
@@ -384,14 +390,10 @@ def _exec_gemm(op: O.GemmSpec, env: _Env, weight, gt: GraphTensors,
 
     # Pallas backends with a typed GEMM: fold the access-scheme gather into
     # the kernel via the padded gather-index layout — the [rows, k] input
-    # copy is never materialized outside the kernel (paper §3.3). The tuned
-    # decision overrides the VMEM-budget heuristic either way.
+    # copy is never materialized outside the kernel (paper §3.3).
     if (backend_eff != "xla" and typed and gmap is not None
             and op.gather in _FUSABLE_GATHERS):
-        fuse = (dec.fuse_gather
-                if dec is not None and dec.fuse_gather is not None
-                else _fits_vmem(x_src, gmap))
-        if fuse:
+        if _fuse(dec, _gather_fits(x_src, gmap, tile_rows or lay.tile)):
             y = K.segment_mm_gather(x_src, w, lay, gmap, row_scale=scale,
                                     backend=backend_eff,
                                     tile_n=tile_n or 128,
@@ -455,9 +457,8 @@ def _exec_traversal(op: O.TraversalSpec, env: _Env, gt: GraphTensors,
                     backend_eff = dec.backend
                 if backend_eff != "xla":
                     # fully fused softmax+aggregate traversal kernel
-                    fuse = (dec.fuse_gather
-                            if dec is not None and dec.fuse_gather is not None
-                            else _fits_vmem(msg, slot_map))
+                    fuse = _fuse(dec, _gather_fits(
+                        msg, slot_map, kl.blocked.edge_tile))
                     out = K.edge_softmax_agg(
                         scores, msg, gt.dst, gt.num_nodes,
                         bc=kl.blocked, backend=backend_eff,
@@ -506,9 +507,8 @@ def _exec_traversal(op: O.TraversalSpec, env: _Env, gt: GraphTensors,
             backend_eff = backend
             if dec is not None and dec.backend != tspace.DEFAULT:
                 backend_eff = dec.backend
-            fuse = (dec.fuse_gather
-                    if dec is not None and dec.fuse_gather is not None
-                    else _fits_vmem(msg, slot_map))
+            fuse = _fuse(dec, _gather_fits(msg, slot_map,
+                                         kl.blocked.edge_tile))
             scale = None
             if s.scale is not None:
                 scale = env.get_edge_vanilla(s.scale)

@@ -15,10 +15,10 @@ executable per bucket: zero retraces, zero Python op dispatch. Cache hits,
 misses, and actual traces are counted so tests and the serve_cached
 benchmark can assert the steady state.
 
-Input features are donated to the compiled call on accelerator backends
-(they are freshly gathered per batch, so the executable may reuse their
-buffers for outputs); donation is skipped on CPU where XLA does not
-implement it.
+The training steps donate the optimizer state on every backend, the CPU
+included: the new state has the old one's shapes, so the update runs in
+place, and the old state is deleted by the call. Input features are not
+donated: no output has their shape, so XLA could never reuse them.
 """
 from __future__ import annotations
 
@@ -43,10 +43,6 @@ def signature(args) -> tuple:
         (jnp.shape(l), jnp.result_type(l).name) for l in leaves)
 
 
-def _donation_supported() -> bool:
-    return jax.default_backend() not in ("cpu",)
-
-
 class _CachedExecutor:
     """Shared machinery: explicit signature -> jitted-callable cache.
 
@@ -56,10 +52,9 @@ class _CachedExecutor:
     for the old variants.
     """
 
-    def __init__(self, donate: bool, donate_argnums: Sequence[int],
-                 decisions=None, static_key: tuple = ()):
+    def __init__(self, donate_argnums: Sequence[int] = (), decisions=None,
+                 static_key: tuple = ()):
         self._cache: Dict[tuple, object] = {}
-        self._donate = donate and _donation_supported()
         self._donate_argnums = tuple(donate_argnums)
         # plan fingerprint(s): distinct lowered plans can never share a
         # compiled executable even if their argument signatures collide
@@ -95,8 +90,7 @@ class _CachedExecutor:
             self.cache_misses += 1
             obs.metrics().counter("executor_cache_misses",
                                   executor=type(self).__name__).inc()
-            donate = self._donate_argnums if self._donate else ()
-            fn = jax.jit(self._traced, donate_argnums=donate)
+            fn = jax.jit(self._traced, donate_argnums=self._donate_argnums)
             self._cache[key] = fn
         else:
             self.cache_hits += 1
@@ -123,16 +117,10 @@ class PlanExecutor(_CachedExecutor):
     ``gt``/``kl`` are arguments (not closure state), so one executor serves
     any graph whose signature matches — and distinct graphs simply occupy
     distinct cache entries.
-
-    Donation defaults off here: full-graph callers typically reuse the same
-    feature arrays across calls, so their buffers are not ours to consume
-    (unlike the per-batch gathered features of ``BlockExecutor``).
     """
 
-    def __init__(self, plan, backend: str = "xla",
-                 donate_feats: bool = False, decisions=None):
-        super().__init__(donate_feats, donate_argnums=(3,),
-                         decisions=decisions,
+    def __init__(self, plan, backend: str = "xla", decisions=None):
+        super().__init__(decisions=decisions,
                          static_key=(plan.fingerprint(),))
         self.plan = plan
         self.backend = backend
@@ -156,10 +144,8 @@ class BlockExecutor(_CachedExecutor):
     """
 
     def __init__(self, plans: Sequence, backend: str = "xla",
-                 activation: str = "relu", donate_feats: bool = True,
-                 decisions=None):
-        super().__init__(donate_feats, donate_argnums=(5,),
-                         decisions=decisions,
+                 activation: str = "relu", decisions=None):
+        super().__init__(decisions=decisions,
                          static_key=tuple(p.fingerprint() for p in plans))
         self.plans = list(plans)
         self.backend = backend
@@ -185,7 +171,7 @@ class BlockExecutor(_CachedExecutor):
         Input-feature precedence: an explicit ``feats`` pytree, then the
         loader-attached ``mb.feats`` (pre-gathered by a tiered feature
         store inside the prefetch overlap), then an on-device gather from
-        ``global_feats``. The chosen buffers are donated."""
+        ``global_feats``."""
         if feats is None:
             feats = getattr(mb, "feats", None)
         if feats is None:
@@ -217,17 +203,13 @@ class BlockTrainExecutor(_CachedExecutor):
     forward executors, so shape-bucketed mini-batches retrace zero times
     after warmup.
 
-    The optimizer state is donated on accelerator backends (its buffers are
-    consumed by the update — callers must not reuse the old state), as are
-    the per-batch gathered features.
+    The optimizer state is donated (its buffers are consumed by the update —
+    callers must not reuse the old state).
     """
 
     def __init__(self, plans: Sequence, opt, backend: str = "xla",
-                 activation: str = "relu", donate_state: bool = True,
-                 decisions=None):
-        # argnums in _traced order: 0=state, 6=feats
-        super().__init__(donate_state, donate_argnums=(0, 6),
-                         decisions=decisions,
+                 activation: str = "relu", decisions=None):
+        super().__init__(donate_argnums=(0,), decisions=decisions,
                          static_key=tuple(p.fingerprint() for p in plans))
         self.plans = list(plans)
         self.opt = opt
@@ -273,10 +255,8 @@ class StackTrainExecutor(_CachedExecutor):
     """
 
     def __init__(self, plans: Sequence, opt, backend: str = "xla",
-                 activation: str = "relu", donate_state: bool = True,
-                 decisions=None):
-        super().__init__(donate_state, donate_argnums=(0,),
-                         decisions=decisions,
+                 activation: str = "relu", decisions=None):
+        super().__init__(donate_argnums=(0,), decisions=decisions,
                          static_key=tuple(p.fingerprint() for p in plans))
         self.plans = list(plans)
         self.opt = opt

@@ -26,7 +26,7 @@ communication inside the compiled step:
 
 Everything is replicated except the stacked shard-axis arrays, so the
 callable needs zero per-step host synchronization; the optimizer state is
-donated on accelerator backends exactly like ``BlockTrainExecutor``.
+donated exactly like ``BlockTrainExecutor``.
 """
 from __future__ import annotations
 
@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as PS
 
 from repro.compat import shard_map
@@ -62,17 +63,24 @@ class _ShardedExecutor(_CachedExecutor):
     """Shared plumbing: plans + data mesh + the per-shard forward."""
 
     def __init__(self, plans: Sequence, mesh, backend: str = "xla",
-                 activation: str = "relu", donate: bool = False,
-                 donate_argnums: Sequence[int] = (), decisions=None,
-                 tag: str = ""):
-        super().__init__(donate, donate_argnums=donate_argnums,
-                         decisions=decisions,
+                 activation: str = "relu", donate_argnums: Sequence[int] = (),
+                 decisions=None, tag: str = ""):
+        super().__init__(donate_argnums=donate_argnums, decisions=decisions,
                          static_key=(tag, _mesh_key(mesh))
                          + tuple(p.fingerprint() for p in plans))
         self.plans = list(plans)
         self.mesh = mesh
         self.backend = backend
         self.activation = activation
+
+    def _place(self, replicated, sharded):
+        """Put a step's operands on the data mesh — the stacked shard-axis
+        operands split over ``"data"``, the rest replicated — instead of
+        leaving them on the device that built them. Operands already
+        placed so are not moved."""
+        return (jax.device_put(replicated, NamedSharding(self.mesh, PS())),
+                jax.device_put(sharded,
+                               NamedSharding(self.mesh, PS("data"))))
 
     def _forward_one(self, params, full_feats, shard):
         """One shard's block forward from the gathered feature table."""
@@ -118,10 +126,12 @@ class ShardedServeExecutor(_ShardedExecutor):
         """Logits for ``smb.seeds`` (request order) from the per-owner
         feature slabs ``own_feats [P, n_own, d]``."""
         _num_local(self.mesh, smb.num_shards)
-        return self._call(params, own_feats, list(smb.tensors),
-                          list(smb.layouts), list(smb.dst_locals),
-                          smb.seed_perm, smb.owner_rows, smb.local_rows,
-                          smb.route)
+        (params, route), sharded = self._place(
+            (params, smb.route),
+            (own_feats, list(smb.tensors), list(smb.layouts),
+             list(smb.dst_locals), smb.seed_perm, smb.owner_rows,
+             smb.local_rows))
+        return self._call(params, *sharded, route)
 
 
 class ShardedTrainExecutor(_ShardedExecutor):
@@ -130,11 +140,10 @@ class ShardedTrainExecutor(_ShardedExecutor):
     update, request-order loss/accuracy — one dispatch per step."""
 
     def __init__(self, plans: Sequence, opt, mesh, backend: str = "xla",
-                 activation: str = "relu", donate_state: bool = True,
-                 decisions=None):
+                 activation: str = "relu", decisions=None):
         super().__init__(plans, mesh, backend, activation,
-                         donate=donate_state, donate_argnums=(0,),
-                         decisions=decisions, tag="train")
+                         donate_argnums=(0,), decisions=decisions,
+                         tag="train")
         self.opt = opt
 
     def _traced(self, state, own_feats, gts, kls, dstl, perm, orow, lrow,
@@ -200,11 +209,12 @@ class ShardedTrainExecutor(_ShardedExecutor):
         """
         _num_local(self.mesh, smb.num_shards)
         inv_b = jnp.float32(1.0 / len(smb.seeds))
-        return self._call(state, own_feats, list(smb.tensors),
-                          list(smb.layouts), list(smb.dst_locals),
-                          smb.seed_perm, smb.owner_rows, smb.local_rows,
-                          smb.slice_labels(labels), smb.mask, smb.route,
-                          inv_b)
+        (state, route, inv_b), sharded = self._place(
+            (state, smb.route, inv_b),
+            (own_feats, list(smb.tensors), list(smb.layouts),
+             list(smb.dst_locals), smb.seed_perm, smb.owner_rows,
+             smb.local_rows, smb.slice_labels(labels), smb.mask))
+        return self._call(state, *sharded, route, inv_b)
 
     def lowered_hlo(self, state, smb, labels, own_feats) -> str:
         """Lowered (StableHLO) text of the whole train step for these

@@ -25,8 +25,7 @@ OGB-size inputs. This package makes storage *tiered*:
   sampled row ids. Hits never leave the device: the per-batch features
   are produced by one jitted insert+gather program whose cache state is
   threaded through as a **donated** input, so the slab is updated in
-  place on accelerator backends and a fully-hot batch performs zero host
-  feature work.
+  place and a fully-hot batch performs zero host feature work.
 
 All three backends return bitwise-identical feature rows (the bits only
 ever move; they are never recomputed), which is what lets every execution
@@ -55,29 +54,6 @@ import jax.numpy as jnp
 from repro import obs
 from repro.core.graph import HeteroGraph
 from repro.kernels.layout import pow2ceil
-
-
-_DONATION: Optional[bool] = None
-
-
-def _donation_supported() -> bool:
-    """Probe (once) whether the active backend honors buffer donation.
-
-    Modern XLA:CPU aliases donated buffers just like GPU/TPU; older
-    builds emit an "unused donation" warning and silently copy. Probing
-    beats a backend allowlist: the slab update in ``CachedFeatureStore``
-    is in-place wherever the runtime allows, and falls back to the
-    functional copy (still bitwise-identical) where it does not."""
-    global _DONATION
-    if _DONATION is None:
-        import warnings
-        probe = jax.jit(lambda x: x.at[0].set(1.0), donate_argnums=(0,))
-        with warnings.catch_warnings(record=True) as caught:
-            warnings.simplefilter("always")
-            jax.block_until_ready(probe(jnp.zeros((2,), jnp.float32)))
-        _DONATION = not any("donat" in str(w.message).lower()
-                            for w in caught)
-    return _DONATION
 
 
 def split_budget(graph: HeteroGraph, budget: int,
@@ -275,8 +251,8 @@ class CachedFeatureStore(HostFeatureStore):
        one jitted program scatters them into their slots (pad/overflow
        rows carry slot index ``S`` and drop) and gathers the batch's
        ``[n, dim]`` features from ``concat(slots, shipped)`` — cache hits
-       therefore never leave the device. ``slots`` is donated on
-       accelerator backends: the slab updates in place, and state is
+       therefore never leave the device. ``slots`` is donated: the slab
+       updates in place, and state is
        threaded functionally (``self.slots`` is rebound to the program's
        output every batch).
     3. a fully-hot batch (zero misses) runs a read-only gather program:
@@ -323,9 +299,8 @@ class CachedFeatureStore(HostFeatureStore):
         self.evictions = 0
         self.overflows = 0
         self.trace_count = 0   # (re)traces of the two gather programs
-        donate = (0,) if _donation_supported() else ()
         self._insert_fn = jax.jit(self._traced_insert_gather,
-                                  donate_argnums=donate)
+                                  donate_argnums=(0,))
         self._hot_fn = jax.jit(self._traced_hot_gather)
         self._warmed: set = set()   # idx lengths whose programs are built
 

@@ -154,10 +154,11 @@ def _segment_mm_xla_padded(x_p, w, t2g, scale_p, tile):
 
 
 def _fit_tile_n(n: int, tile_n: int) -> int:
-    """Largest usable column tile: ``tile_n`` capped at ``n``, falling back
-    to ``n`` itself when it does not divide evenly."""
+    """Column tile the TPU accepts: ``tile_n`` capped at ``n`` when that is
+    ``n`` itself or a multiple of the 128-lane tile dividing ``n``; else
+    the whole of ``n``."""
     tn = min(tile_n, n)
-    return n if n % tn else tn
+    return tn if tn == n or (tn % 128 == 0 and n % tn == 0) else n
 
 
 def _fit_tile_rows(lay_tile: int, tile_rows: Optional[int]) -> int:

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.traversal import gather_rows
+
 
 def _mm_kernel(t2g_ref, x_ref, w_ref, y_ref):
     acc = jnp.dot(x_ref[...], w_ref[0], preferred_element_type=jnp.float32)
@@ -84,31 +86,17 @@ def segment_mm_padded(
     )(t2g, *args)
 
 
-def _mm_gather_tile(gidx_ref, x_ref, tile_rows):
-    """Gather this grid step's row tile from the resident source block.
-
-    ``gidx_ref`` is the scalar-prefetched padded gather-index layout
-    (kernels/layout.py ``compose_gather_rows``): slot -> source row or -1.
-    The gather happens here, inside the kernel, against the full source
-    block in VMEM — the TPU analogue of the paper's per-element gather
-    access scheme folded into the GEMM template.
-    """
-    t = pl.program_id(0)
-    rows = gidx_ref[pl.ds(t * tile_rows, tile_rows)]
-    valid = rows >= 0
-    xt = jnp.take(x_ref[...], jnp.where(valid, rows, 0), axis=0)
-    return jnp.where(valid[:, None], xt, 0.0).astype(x_ref.dtype)
-
-
-def _mm_gather_kernel(gidx_ref, t2g_ref, x_ref, w_ref, y_ref, *, tile_rows):
-    xt = _mm_gather_tile(gidx_ref, x_ref, tile_rows)
+def _mm_gather_kernel(gidx_ref, t2g_ref, x_ref, w_ref, y_ref, buf_ref):
+    xt = gather_rows(gidx_ref, pl.program_id(0) * buf_ref.shape[0], x_ref,
+                     buf_ref)
     acc = jnp.dot(xt, w_ref[0], preferred_element_type=jnp.float32)
     y_ref[...] = acc.astype(y_ref.dtype)
 
 
 def _mm_gather_scale_kernel(gidx_ref, t2g_ref, x_ref, w_ref, scale_ref, y_ref,
-                            *, tile_rows):
-    xt = _mm_gather_tile(gidx_ref, x_ref, tile_rows)
+                            buf_ref):
+    xt = gather_rows(gidx_ref, pl.program_id(0) * buf_ref.shape[0], x_ref,
+                     buf_ref)
     acc = jnp.dot(xt, w_ref[0], preferred_element_type=jnp.float32)
     acc = acc * scale_ref[...].astype(jnp.float32)
     y_ref[...] = acc.astype(y_ref.dtype)
@@ -132,9 +120,9 @@ def segment_mm_gather_padded(
 
     Unlike ``segment_mm_padded`` the caller hands over the *ungathered*
     source tensor; the per-row gather runs inside the kernel from the
-    scalar-prefetched index layout, so no ``[Rp, k]`` (edge-wide) input copy
-    is ever materialized in HBM. The source block stays resident in VMEM
-    across grid steps (its index_map is constant).
+    scalar-prefetched (SMEM) index layout, so no ``[Rp, k]`` (edge-wide)
+    input copy is ever materialized in HBM. The source block stays resident
+    in VMEM across grid steps (its index_map is constant).
     """
     nx, k = x.shape
     r, k2, n = w.shape
@@ -151,13 +139,12 @@ def segment_mm_gather_padded(
         pl.BlockSpec((1, k, tile_n), lambda i, j, gidx, t2g: (t2g[i], 0, j)),
     ]
     args = [x, w]
-    kernel = functools.partial(_mm_gather_kernel, tile_rows=tile_rows)
+    kernel = _mm_gather_kernel
     if row_scale_p is not None:
         in_specs.append(
             pl.BlockSpec((tile_rows, 1), lambda i, j, gidx, t2g: (i, 0)))
         args.append(row_scale_p.reshape(rp, 1))
-        kernel = functools.partial(_mm_gather_scale_kernel,
-                                   tile_rows=tile_rows)
+        kernel = _mm_gather_scale_kernel
 
     return pl.pallas_call(
         kernel,
@@ -167,6 +154,7 @@ def segment_mm_gather_padded(
             in_specs=in_specs,
             out_specs=pl.BlockSpec((tile_rows, tile_n),
                                    lambda i, j, gidx, t2g: (i, j)),
+            scratch_shapes=[pltpu.VMEM((tile_rows, k), x.dtype)],
         ),
         out_shape=jax.ShapeDtypeStruct((rp, n), x.dtype),
         interpret=interpret,

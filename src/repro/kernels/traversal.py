@@ -17,17 +17,26 @@ Kernels (all derived traversal-template instances):
                             canonical fused traversal region of Listing 1).
 ``seg_weighted_agg_padded`` out[v] = Σ_e scale_e · msg_e (RGCN-style).
 
-The scatter "one-hot × message" contraction maps the per-edge scatter onto
-the MXU (a [node_block × tile] one-hot matmul) instead of per-element stores.
+The scatter maps onto the MXU: each tile builds a ``[node_block, tile]``
+one-hot of its edges' destinations, scales it by the per-edge weight, and
+contracts it with the tile's messages. The same one-hot also reads each
+edge's destination statistics (a masked column reduction), so no kernel
+indexes a vector by a vector.
+
+Block shapes follow the TPU rule that a block's last two dims divide by
+(8, 128) or equal the array's: per-tile scalars travel as ``[T, 1, tile]``
+with the tile dim squeezed, and per-node statistics as ``[NBk * NB, 1]``
+columns, one ``(node_block, 1)`` block per destination-node block.
 
 ``*_gather_padded`` variants additionally fold the message gather into the
 kernel: instead of materializing the padded dst-sorted ``[Ep, d]`` message
 copy in HBM before the call, the caller passes messages in their storage
 order (canonical edge order, or the compact unique-pair table) plus a
 scalar-prefetched padded row-index map (slot -> message row, -1 for pads);
-each grid step gathers its tile from the VMEM-resident message block —
-the paper's in-kernel gather access scheme applied to the traversal
-template.
+each grid step copies its tile row by row from the VMEM-resident message
+block — the paper's in-kernel gather access scheme applied to the traversal
+template. The map lives in SMEM, so its size is bounded by SMEM, not VMEM
+(see ``tune/device.py``).
 """
 from __future__ import annotations
 
@@ -41,32 +50,93 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
-def _stats_kernel(meta_ref, scores_ref, dst_ref, mx_ref, den_ref, *, node_block):
-    t = pl.program_id(0)
-    is_first = meta_ref[1, t]
+def gather_rows(map_ref, base, src_ref, buf_ref):
+    """``buf[r] = src[map[base + r]]`` for every row of ``buf``; rows whose
+    map entry is -1 become zeros. The map is read as SMEM scalars and each
+    source row as a one-row VMEM slice — the TPU compiler accepts neither a
+    vector load from SMEM nor a vector-indexed gather from VMEM."""
 
-    @pl.when(is_first == 1)
+    def body(r, carry):
+        row = map_ref[base + r]
+        v = src_ref[pl.ds(jnp.maximum(row, 0), 1), :].astype(buf_ref.dtype)
+        buf_ref[pl.ds(r, 1), :] = jnp.where(row >= 0, v, jnp.zeros_like(v))
+        return carry
+
+    jax.lax.fori_loop(0, buf_ref.shape[0], body, 0)
+    return buf_ref[...]
+
+
+def _block_meta(t2b: jnp.ndarray) -> jnp.ndarray:
+    """``[2, T]`` scalar-prefetch table: row 0 the tile -> node-block map,
+    row 1 whether the tile is its block's first (which zeroes the block)."""
+    prev = jnp.concatenate([jnp.array([-1], jnp.int32), t2b[:-1]])
+    return jnp.stack([t2b.astype(jnp.int32), (t2b != prev).astype(jnp.int32)])
+
+
+def _tile_spec(tile):
+    """One edge tile's row of a ``[T, 1, tile]`` per-edge scalar array."""
+    return pl.BlockSpec((None, 1, tile), lambda t, *pref: (t, 0, 0))
+
+
+def _node_spec(node_block, cols):
+    """The destination-node block a tile accumulates into (``meta`` is the
+    last scalar-prefetch operand)."""
+    return pl.BlockSpec((node_block, cols),
+                        lambda t, *pref: (pref[-1][0, t], 0))
+
+
+def _first_tile(meta_ref):
+    return meta_ref[1, pl.program_id(0)] == 1
+
+
+def _onehot(dst_ref, node_block):
+    """``[node_block, tile]`` mask of each edge's local destination row;
+    pad edges (dst == node_block) match no row."""
+    dst = dst_ref[...]                                   # [1, tile]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (node_block, dst.shape[-1]), 0)
+    return rows == dst
+
+
+def _per_edge(mask, col):
+    """Each edge's value of a per-node column ``[node_block, 1]`` -> [1, tile]
+    (zeros for pad edges)."""
+    return jnp.sum(jnp.where(mask, col, 0.0), axis=0, keepdims=True)
+
+
+def _softmax_weights(mask, scores_ref, mx_ref, den_ref):
+    """One-hot scaled by each edge's softmax weight -> [node_block, tile]."""
+    s = scores_ref[...].astype(jnp.float32)              # [1, tile]
+    att = jnp.exp(s - _per_edge(mask, mx_ref[...])) / jnp.maximum(
+        _per_edge(mask, den_ref[...]), 1e-38)
+    return jnp.where(mask, att, 0.0)
+
+
+def _accumulate(meta_ref, out_ref, weights, msg):
+    @pl.when(_first_tile(meta_ref))
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    contrib = jax.lax.dot(weights, msg.astype(jnp.float32),
+                          preferred_element_type=jnp.float32)  # [NB, d]
+    out_ref[...] += contrib.astype(out_ref.dtype)
+
+
+def _stats_kernel(meta_ref, scores_ref, dst_ref, mx_ref, den_ref, *, node_block):
+    @pl.when(_first_tile(meta_ref))
     def _init():
         mx_ref[...] = jnp.full_like(mx_ref, _NEG_INF)
         den_ref[...] = jnp.zeros_like(den_ref)
 
-    s = scores_ref[0, :].astype(jnp.float32)          # [tile]
-    dst = dst_ref[0, :]                               # [tile], pads == node_block
-    tile = s.shape[0]
-    node_ids = jax.lax.broadcasted_iota(jnp.int32, (node_block, tile), 0)
-    mask = node_ids == dst[None, :]                   # [NB, tile]
-    masked = jnp.where(mask, s[None, :], _NEG_INF)
-    m_tile = jnp.max(masked, axis=1)                  # [NB]
-
-    m_old = mx_ref[0, :]
-    m_new = jnp.maximum(m_old, m_tile)
+    mask = _onehot(dst_ref, node_block)                  # [NB, tile]
+    masked = jnp.where(mask, scores_ref[...].astype(jnp.float32), _NEG_INF)
+    m_old = mx_ref[...]                                  # [NB, 1]
+    m_new = jnp.maximum(m_old, jnp.max(masked, axis=1, keepdims=True))
     # online rescale; guard -inf - -inf
     old_factor = jnp.where(m_old <= _NEG_INF, 0.0, jnp.exp(m_old - m_new))
-    t_den = jnp.sum(
-        jnp.where(mask, jnp.exp(masked - m_new[:, None]), 0.0), axis=1
-    )
-    mx_ref[0, :] = m_new
-    den_ref[0, :] = den_ref[0, :] * old_factor + t_den
+    t_den = jnp.sum(jnp.where(mask, jnp.exp(masked - m_new), 0.0), axis=1,
+                    keepdims=True)
+    mx_ref[...] = m_new
+    den_ref[...] = den_ref[...] * old_factor + t_den
 
 
 @functools.partial(
@@ -81,59 +151,30 @@ def seg_stats_padded(
     num_node_blocks: int,
     interpret: bool = False,
 ):
+    """-> (max, sum-exp), each ``[num_node_blocks * node_block, 1]``."""
     num_tiles, tile = scores_p.shape
-    prev = jnp.concatenate([jnp.array([-1], jnp.int32), t2b[:-1]])
-    meta = jnp.stack([t2b.astype(jnp.int32), (t2b != prev).astype(jnp.int32)])
-
-    mx, den = pl.pallas_call(
+    stat = jax.ShapeDtypeStruct((num_node_blocks * node_block, 1),
+                                jnp.float32)
+    return pl.pallas_call(
         functools.partial(_stats_kernel, node_block=node_block),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(num_tiles,),
-            in_specs=[
-                pl.BlockSpec((1, tile), lambda t, meta: (t, 0)),
-                pl.BlockSpec((1, tile), lambda t, meta: (t, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, node_block), lambda t, meta: (meta[0, t], 0)),
-                pl.BlockSpec((1, node_block), lambda t, meta: (meta[0, t], 0)),
-            ],
+            in_specs=[_tile_spec(tile), _tile_spec(tile)],
+            out_specs=[_node_spec(node_block, 1), _node_spec(node_block, 1)],
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((num_node_blocks, node_block), jnp.float32),
-            jax.ShapeDtypeStruct((num_node_blocks, node_block), jnp.float32),
-        ],
+        out_shape=[stat, stat],
         interpret=interpret,
-    )(meta, scores_p, local_dst_p)
-    return mx, den
+    )(_block_meta(t2b), scores_p.reshape(num_tiles, 1, tile),
+      local_dst_p.reshape(num_tiles, 1, tile))
 
 
-def _softmax_agg_kernel(meta_ref, scores_ref, dst_ref, msg_ref, mx_ref, den_ref,
-                        out_ref, *, node_block):
-    t = pl.program_id(0)
-    is_first = meta_ref[1, t]
-
-    @pl.when(is_first == 1)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    s = scores_ref[0, :].astype(jnp.float32)          # [tile]
-    dst = dst_ref[0, :]                               # [tile]
-    tile = s.shape[0]
-    valid = dst < node_block
-    dst_c = jnp.where(valid, dst, 0)
-    mx = mx_ref[0, :]
-    den = den_ref[0, :]
-    att = jnp.exp(s - mx[dst_c]) / jnp.maximum(den[dst_c], 1e-38)
-    att = jnp.where(valid, att, 0.0)                  # [tile]
-
-    node_ids = jax.lax.broadcasted_iota(jnp.int32, (node_block, tile), 0)
-    onehot = (node_ids == dst[None, :]).astype(jnp.float32)
-    contrib = jax.lax.dot(
-        onehot, att[:, None] * msg_ref[...].astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    )                                                 # [NB, d]
-    out_ref[...] += contrib.astype(out_ref.dtype)
+def _softmax_agg_kernel(meta_ref, scores_ref, dst_ref, msg_ref, mx_ref,
+                        den_ref, out_ref, *, node_block):
+    mask = _onehot(dst_ref, node_block)
+    _accumulate(meta_ref, out_ref,
+                _softmax_weights(mask, scores_ref, mx_ref, den_ref),
+                msg_ref[...])
 
 
 @functools.partial(
@@ -144,8 +185,8 @@ def seg_softmax_agg_padded(
     msg_p: jnp.ndarray,        # [T*tile, d]  dst-sorted padded messages
     local_dst_p: jnp.ndarray,  # [T, tile]
     t2b: jnp.ndarray,          # [T]
-    mx: jnp.ndarray,           # [NBk, NB]  from seg_stats_padded
-    den: jnp.ndarray,          # [NBk, NB]
+    mx: jnp.ndarray,           # [NBk*NB, 1]  from seg_stats_padded
+    den: jnp.ndarray,          # [NBk*NB, 1]
     *,
     node_block: int,
     num_node_blocks: int,
@@ -153,69 +194,35 @@ def seg_softmax_agg_padded(
 ) -> jnp.ndarray:
     num_tiles, tile = scores_p.shape
     d = msg_p.shape[-1]
-    prev = jnp.concatenate([jnp.array([-1], jnp.int32), t2b[:-1]])
-    meta = jnp.stack([t2b.astype(jnp.int32), (t2b != prev).astype(jnp.int32)])
-
     return pl.pallas_call(
         functools.partial(_softmax_agg_kernel, node_block=node_block),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(num_tiles,),
             in_specs=[
-                pl.BlockSpec((1, tile), lambda t, meta: (t, 0)),
-                pl.BlockSpec((1, tile), lambda t, meta: (t, 0)),
+                _tile_spec(tile),
+                _tile_spec(tile),
                 pl.BlockSpec((tile, d), lambda t, meta: (t, 0)),
-                pl.BlockSpec((1, node_block), lambda t, meta: (meta[0, t], 0)),
-                pl.BlockSpec((1, node_block), lambda t, meta: (meta[0, t], 0)),
+                _node_spec(node_block, 1),
+                _node_spec(node_block, 1),
             ],
-            out_specs=pl.BlockSpec(
-                (node_block, d), lambda t, meta: (meta[0, t], 0)
-            ),
+            out_specs=_node_spec(node_block, d),
         ),
         out_shape=jax.ShapeDtypeStruct((num_node_blocks * node_block, d),
                                        msg_p.dtype),
         interpret=interpret,
-    )(meta, scores_p, local_dst_p, msg_p, mx, den)
-
-
-def _gather_msg_tile(mmap_ref, msg_ref, tile):
-    """In-kernel message gather: this grid step's tile of rows from the
-    VMEM-resident message block, via the scalar-prefetched slot -> row map
-    (-1 slots produce zero rows)."""
-    t = pl.program_id(0)
-    rows = mmap_ref[pl.ds(t * tile, tile)]
-    valid = rows >= 0
-    mt = jnp.take(msg_ref[...], jnp.where(valid, rows, 0), axis=0)
-    return jnp.where(valid[:, None], mt.astype(jnp.float32), 0.0)
+    )(_block_meta(t2b), scores_p.reshape(num_tiles, 1, tile),
+      local_dst_p.reshape(num_tiles, 1, tile), msg_p, mx, den)
 
 
 def _softmax_agg_gather_kernel(mmap_ref, meta_ref, scores_ref, dst_ref,
-                               msg_ref, mx_ref, den_ref, out_ref, *,
+                               msg_ref, mx_ref, den_ref, out_ref, buf_ref, *,
                                node_block):
-    t = pl.program_id(0)
-    is_first = meta_ref[1, t]
-
-    @pl.when(is_first == 1)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    s = scores_ref[0, :].astype(jnp.float32)          # [tile]
-    dst = dst_ref[0, :]                               # [tile]
-    tile = s.shape[0]
-    valid = dst < node_block
-    dst_c = jnp.where(valid, dst, 0)
-    mx = mx_ref[0, :]
-    den = den_ref[0, :]
-    att = jnp.exp(s - mx[dst_c]) / jnp.maximum(den[dst_c], 1e-38)
-    att = jnp.where(valid, att, 0.0)                  # [tile]
-
-    msg_t = _gather_msg_tile(mmap_ref, msg_ref, tile)  # [tile, d]
-    node_ids = jax.lax.broadcasted_iota(jnp.int32, (node_block, tile), 0)
-    onehot = (node_ids == dst[None, :]).astype(jnp.float32)
-    contrib = jax.lax.dot(
-        onehot, att[:, None] * msg_t, preferred_element_type=jnp.float32,
-    )                                                 # [NB, d]
-    out_ref[...] += contrib.astype(out_ref.dtype)
+    tile = buf_ref.shape[0]
+    msg = gather_rows(mmap_ref, pl.program_id(0) * tile, msg_ref, buf_ref)
+    mask = _onehot(dst_ref, node_block)
+    _accumulate(meta_ref, out_ref,
+                _softmax_weights(mask, scores_ref, mx_ref, den_ref), msg)
 
 
 @functools.partial(
@@ -227,8 +234,8 @@ def seg_softmax_agg_gather_padded(
     mmap: jnp.ndarray,         # [T*tile] int32 slot -> message row, or -1
     local_dst_p: jnp.ndarray,  # [T, tile]
     t2b: jnp.ndarray,          # [T]
-    mx: jnp.ndarray,           # [NBk, NB]  from seg_stats_padded
-    den: jnp.ndarray,          # [NBk, NB]
+    mx: jnp.ndarray,           # [NBk*NB, 1]  from seg_stats_padded
+    den: jnp.ndarray,          # [NBk*NB, 1]
     *,
     node_block: int,
     num_node_blocks: int,
@@ -239,53 +246,35 @@ def seg_softmax_agg_gather_padded(
     compact unique table), never materialized per padded slot in HBM."""
     num_tiles, tile = scores_p.shape
     em, d = msg.shape
-    prev = jnp.concatenate([jnp.array([-1], jnp.int32), t2b[:-1]])
-    meta = jnp.stack([t2b.astype(jnp.int32), (t2b != prev).astype(jnp.int32)])
-
     return pl.pallas_call(
         functools.partial(_softmax_agg_gather_kernel, node_block=node_block),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(num_tiles,),
             in_specs=[
-                pl.BlockSpec((1, tile), lambda t, mmap, meta: (t, 0)),
-                pl.BlockSpec((1, tile), lambda t, mmap, meta: (t, 0)),
+                _tile_spec(tile),
+                _tile_spec(tile),
                 pl.BlockSpec((em, d), lambda t, mmap, meta: (0, 0)),
-                pl.BlockSpec((1, node_block),
-                             lambda t, mmap, meta: (meta[0, t], 0)),
-                pl.BlockSpec((1, node_block),
-                             lambda t, mmap, meta: (meta[0, t], 0)),
+                _node_spec(node_block, 1),
+                _node_spec(node_block, 1),
             ],
-            out_specs=pl.BlockSpec(
-                (node_block, d), lambda t, mmap, meta: (meta[0, t], 0)
-            ),
+            out_specs=_node_spec(node_block, d),
+            scratch_shapes=[pltpu.VMEM((tile, d), msg.dtype)],
         ),
         out_shape=jax.ShapeDtypeStruct((num_node_blocks * node_block, d),
                                        msg.dtype),
         interpret=interpret,
-    )(mmap, meta, scores_p, local_dst_p, msg, mx, den)
+    )(mmap, _block_meta(t2b), scores_p.reshape(num_tiles, 1, tile),
+      local_dst_p.reshape(num_tiles, 1, tile), msg, mx, den)
 
 
 def _weighted_agg_gather_kernel(mmap_ref, meta_ref, scale_ref, dst_ref,
-                                msg_ref, out_ref, *, node_block):
-    t = pl.program_id(0)
-    is_first = meta_ref[1, t]
-
-    @pl.when(is_first == 1)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    dst = dst_ref[0, :]
-    tile = dst.shape[0]
-    valid = dst < node_block
-    scale = jnp.where(valid, scale_ref[0, :].astype(jnp.float32), 0.0)
-    msg_t = _gather_msg_tile(mmap_ref, msg_ref, tile)
-    node_ids = jax.lax.broadcasted_iota(jnp.int32, (node_block, tile), 0)
-    onehot = (node_ids == dst[None, :]).astype(jnp.float32)
-    contrib = jax.lax.dot(
-        onehot, scale[:, None] * msg_t, preferred_element_type=jnp.float32,
-    )
-    out_ref[...] += contrib.astype(out_ref.dtype)
+                                msg_ref, out_ref, buf_ref, *, node_block):
+    tile = buf_ref.shape[0]
+    msg = gather_rows(mmap_ref, pl.program_id(0) * tile, msg_ref, buf_ref)
+    mask = _onehot(dst_ref, node_block)
+    weights = jnp.where(mask, scale_ref[...].astype(jnp.float32), 0.0)
+    _accumulate(meta_ref, out_ref, weights, msg)
 
 
 @functools.partial(
@@ -305,49 +294,31 @@ def seg_weighted_agg_gather_padded(
     """Gather-fused weighted aggregation (RGCN-style sum/mean numerator)."""
     num_tiles, tile = scale_p.shape
     em, d = msg.shape
-    prev = jnp.concatenate([jnp.array([-1], jnp.int32), t2b[:-1]])
-    meta = jnp.stack([t2b.astype(jnp.int32), (t2b != prev).astype(jnp.int32)])
-
     return pl.pallas_call(
         functools.partial(_weighted_agg_gather_kernel, node_block=node_block),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(num_tiles,),
             in_specs=[
-                pl.BlockSpec((1, tile), lambda t, mmap, meta: (t, 0)),
-                pl.BlockSpec((1, tile), lambda t, mmap, meta: (t, 0)),
+                _tile_spec(tile),
+                _tile_spec(tile),
                 pl.BlockSpec((em, d), lambda t, mmap, meta: (0, 0)),
             ],
-            out_specs=pl.BlockSpec(
-                (node_block, d), lambda t, mmap, meta: (meta[0, t], 0)
-            ),
+            out_specs=_node_spec(node_block, d),
+            scratch_shapes=[pltpu.VMEM((tile, d), msg.dtype)],
         ),
         out_shape=jax.ShapeDtypeStruct((num_node_blocks * node_block, d),
                                        msg.dtype),
         interpret=interpret,
-    )(mmap, meta, scale_p, local_dst_p, msg)
+    )(mmap, _block_meta(t2b), scale_p.reshape(num_tiles, 1, tile),
+      local_dst_p.reshape(num_tiles, 1, tile), msg)
 
 
 def _weighted_agg_kernel(meta_ref, scale_ref, dst_ref, msg_ref, out_ref, *,
                          node_block):
-    t = pl.program_id(0)
-    is_first = meta_ref[1, t]
-
-    @pl.when(is_first == 1)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    dst = dst_ref[0, :]
-    tile = dst.shape[0]
-    valid = dst < node_block
-    scale = jnp.where(valid, scale_ref[0, :].astype(jnp.float32), 0.0)
-    node_ids = jax.lax.broadcasted_iota(jnp.int32, (node_block, tile), 0)
-    onehot = (node_ids == dst[None, :]).astype(jnp.float32)
-    contrib = jax.lax.dot(
-        onehot, scale[:, None] * msg_ref[...].astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    )
-    out_ref[...] += contrib.astype(out_ref.dtype)
+    mask = _onehot(dst_ref, node_block)
+    weights = jnp.where(mask, scale_ref[...].astype(jnp.float32), 0.0)
+    _accumulate(meta_ref, out_ref, weights, msg_ref[...])
 
 
 @functools.partial(
@@ -365,24 +336,20 @@ def seg_weighted_agg_padded(
 ) -> jnp.ndarray:
     num_tiles, tile = scale_p.shape
     d = msg_p.shape[-1]
-    prev = jnp.concatenate([jnp.array([-1], jnp.int32), t2b[:-1]])
-    meta = jnp.stack([t2b.astype(jnp.int32), (t2b != prev).astype(jnp.int32)])
-
     return pl.pallas_call(
         functools.partial(_weighted_agg_kernel, node_block=node_block),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(num_tiles,),
             in_specs=[
-                pl.BlockSpec((1, tile), lambda t, meta: (t, 0)),
-                pl.BlockSpec((1, tile), lambda t, meta: (t, 0)),
+                _tile_spec(tile),
+                _tile_spec(tile),
                 pl.BlockSpec((tile, d), lambda t, meta: (t, 0)),
             ],
-            out_specs=pl.BlockSpec(
-                (node_block, d), lambda t, meta: (meta[0, t], 0)
-            ),
+            out_specs=_node_spec(node_block, d),
         ),
         out_shape=jax.ShapeDtypeStruct((num_node_blocks * node_block, d),
                                        msg_p.dtype),
         interpret=interpret,
-    )(meta, scale_p, local_dst_p, msg_p)
+    )(_block_meta(t2b), scale_p.reshape(num_tiles, 1, tile),
+      local_dst_p.reshape(num_tiles, 1, tile), msg_p)

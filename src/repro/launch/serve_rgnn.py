@@ -31,6 +31,7 @@ import hector
 from repro import obs
 from repro.core.graph import CPU_REDUCED_SCALES as REDUCED_SCALES
 from repro.core.graph import table3_graph
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sampling import SeedStream
 from repro.train.engine import MODEL_PROGRAMS, parse_fanout
 
@@ -664,6 +665,7 @@ def main(argv=None):
                         help="compress the arrival schedule by this factor")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.scale is not None:
         scale = args.scale

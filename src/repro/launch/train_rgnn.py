@@ -28,6 +28,7 @@ import hector
 from repro import obs
 from repro.core.graph import (CPU_REDUCED_SCALES, synthetic_heterograph,
                               table3_graph)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import AdamW, cosine_schedule
 from repro.sampling import EpochSeedStream, SeedStream
 from repro.train import (EngineConfig, MODEL_PROGRAMS, SampledTrainer,
@@ -458,6 +459,7 @@ def main(argv=None):
                     help="attribute one fused compiled SGD step into "
                          "forward / backward / optimizer phases")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.scale is not None:
         scale = args.scale

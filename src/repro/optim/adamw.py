@@ -32,9 +32,11 @@ class AdamW:
     clip_norm: Optional[float] = 1.0
 
     def init(self, params) -> TrainState:
+        """The state owns its buffers: the compiled train steps donate it,
+        so it must not alias the caller's ``params``."""
         zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
         return TrainState(
-            params=params,
+            params=jax.tree.map(jnp.copy, params),
             mu=jax.tree.map(zeros, params),
             nu=jax.tree.map(zeros, params),
             step=jnp.zeros((), jnp.int32),

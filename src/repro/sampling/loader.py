@@ -512,10 +512,10 @@ class MiniBatchLoader:
         """Gather this batch's input-feature rows through the store and
         attach them. Runs on the producer (thread or async-dispatch), so
         the host gather + transfer for batch k+1 overlaps batch k's
-        compute. Cached batches are stored *without* feats: executors
-        donate the feature buffers and the cache state advances every
-        batch, so each occurrence re-gathers (hot rows stay device-side
-        in the cached store, making the re-gather cheap)."""
+        compute. Cached batches are stored *without* feats: the cached
+        store's state advances every batch, so each occurrence re-gathers
+        (hot rows stay device-side in the cached store, making the
+        re-gather cheap)."""
         if self.feature_store is None:
             return mb
         # stores normalize ids themselves: the device tier keeps them on

@@ -293,8 +293,8 @@ class ServingRuntime:
             def fn():
                 mb = mbs[it["i"] % len(mbs)]
                 it["i"] += 1
-                # feats=None: gather through the store per call, so donated
-                # feature buffers are never re-consumed across timed iters
+                # feats=None: gather through the store per call, so every
+                # timed iteration pays the same feature gather
                 return self.engine.forward_minibatch(
                     self.params, dataclasses.replace(mb, feats=None),
                     self.store)

@@ -269,14 +269,16 @@ class RGNNEngine:
                 "the EngineConfig (e.g. hector.compile(..., dp=4))")
 
     def shard_features(self, feats) -> jnp.ndarray:
-        """Per-owner resident feature slabs ``[P, n_own, d]`` (device-put
-        once; the compiled steps all-gather them for halo access).
+        """Per-owner resident feature slabs ``[P, n_own, d]``, placed once
+        on the data mesh (the compiled steps all-gather them for halo
+        access).
 
         ``feats`` may be a raw ``[N, d]`` table or a ``repro.feats`` store:
         with a store, each shard's slab is read through ``host_rows`` — the
         full table is never materialized on device, so shards hold only
         their owned rows (+ whatever the store keeps hot)."""
         self._require_dist()
+        from jax.sharding import NamedSharding, PartitionSpec
         from repro.feats import is_feature_store
         if is_feature_store(feats):
             part = self.partition
@@ -286,8 +288,11 @@ class RGNNEngine:
                 lo, hi = int(part.bounds[p]), int(part.bounds[p + 1])
                 out[p, : hi - lo] = feats.host_rows(
                     np.arange(lo, hi, dtype=np.int64))
-            return jnp.asarray(out)
-        return jnp.asarray(self.partition.shard_features(np.asarray(feats)))
+        else:
+            out = self.partition.shard_features(np.asarray(feats))
+        # each device holds only its own shards' slabs
+        return jax.device_put(out, NamedSharding(
+            self.data_mesh, PartitionSpec("data")))
 
     def dist_serve_executor(self):
         """The compiled multi-shard inference step (cached)."""

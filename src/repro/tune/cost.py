@@ -6,7 +6,8 @@ moved through the memory hierarchy plus a per-grid-step overhead term —
 the two effects the tuning knobs actually trade against each other:
 
 * gather fusion removes the materialized ``[rows, k]`` HBM copy but pins the
-  whole source block (+ index maps) in VMEM — infeasible past the budget;
+  whole source block in VMEM and the index maps in SMEM — infeasible past
+  either budget (the same gate codegen applies);
 * smaller row tiles pay more grid-step overhead (but can win on skewed
   type segments where big tiles are mostly padding);
 * the interpret backend exists for correctness only and is effectively
@@ -36,7 +37,6 @@ def score(key: str, variant, plan_backend: str) -> float:
     if eff == "pallas_interpret" and plan_backend != "pallas_interpret":
         return _INFEASIBLE
     itemsize = _ITEMSIZE.get(info["dtype"], 4)
-    budget = D.fused_gather_budget_bytes()
 
     if info["kind"] == "trav":
         ep, d = info["padded_edges"], info["d"]
@@ -44,12 +44,13 @@ def score(key: str, variant, plan_backend: str) -> float:
         if eff != "xla":
             msg_rows = (info["padded_edges"] if not info["compact_msg"]
                         else max(1, info["padded_edges"] // 2))
-            resident = msg_rows * d * itemsize + ep * 4
+            fits = D.fused_gather_fits(msg_rows, d, itemsize, ep,
+                                       info["edge_tile"])
             fuse = variant.fuse_gather
             if fuse is None:
-                fuse = resident <= budget
+                fuse = fits
             if fuse:
-                if resident > budget:
+                if not fits:
                     return _INFEASIBLE
                 io = msg_rows * d * itemsize
             else:
@@ -62,12 +63,12 @@ def score(key: str, variant, plan_backend: str) -> float:
     tn = min(variant.tile_n or 128, n)
     io = rp * (k + n) * itemsize                     # X in + Y out
     if eff != "xla" and info["fusable"]:
-        resident = x_rows * k * itemsize + rp * 4    # source + gather map
+        fits = D.fused_gather_fits(x_rows, k, itemsize, rp, tr)
         fuse = variant.fuse_gather
         if fuse is None:
-            fuse = resident <= budget
+            fuse = fits
         if fuse:
-            if resident > budget:
+            if not fits:
                 return _INFEASIBLE
             io = x_rows * k * itemsize + rp * n * itemsize
         else:

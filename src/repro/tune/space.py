@@ -141,7 +141,7 @@ def _fit_tile_n(n: int, tile_n: int) -> int:
     """Mirror of ``kernels.ops._fit_tile_n``: the column tile a request
     actually resolves to (used to drop behaviorally identical candidates)."""
     tn = min(tile_n, n)
-    return n if n % tn else tn
+    return tn if tn == n or (tn % 128 == 0 and n % tn == 0) else n
 
 
 def _col_tile_candidates(n: int) -> List[Optional[int]]:

@@ -216,24 +216,60 @@ def test_lower_program_per_var_materialization():
 # VMEM budget (satellite: index bytes counted, device-derived budget)
 # ---------------------------------------------------------------------------
 def test_fits_vmem_counts_index_bytes(monkeypatch):
+    """The source block counts against the VMEM budget (lane-padded,
+    double-buffered); the scalar-prefetched index map against SMEM, apart."""
     from repro.core import codegen
-    src = jnp.zeros((100, 10), jnp.float32)      # 4000 bytes
-    gmap = jnp.zeros((300,), jnp.int32)          # 1200 bytes
-    monkeypatch.setenv(BUDGET_ENV, "5000")
-    assert codegen._fits_vmem(src)               # 4000 <= 5000
-    assert not codegen._fits_vmem(src, gmap)     # 5200 > 5000: maps count
-    monkeypatch.setenv(BUDGET_ENV, "6000")
-    assert codegen._fits_vmem(src, gmap)
-    assert codegen._fits_vmem(src, None)         # absent maps are free
+    src = jnp.zeros((100, 10), jnp.float32)      # 2 * 104 * 128 * 4 bytes
+    monkeypatch.setenv(BUDGET_ENV, str(2 * 104 * 128 * 4))
+    assert codegen._gather_fits(src)
+    assert codegen._gather_fits(src, None)         # absent maps are free
+    assert codegen._gather_fits(src, jnp.zeros((1000,), jnp.int32), 128)
+    monkeypatch.setenv(BUDGET_ENV, str(2 * 104 * 128 * 4 - 1))
+    assert not codegen._gather_fits(src)
+    # 200k slots (800 kB + tile table) exceed half of the 1 MiB SMEM: no
+    # VMEM budget, however large, makes that map fit
+    big_map = jnp.zeros((200_000,), jnp.int32)
+    monkeypatch.setenv(BUDGET_ENV, str(10**12))
+    assert codegen._gather_fits(src, big_map[:100_000], 128)
+    assert not codegen._gather_fits(src, big_map, 128)
+    # the in-kernel row gather moves 32-bit rows only
+    assert not codegen._gather_fits(src.astype(jnp.bfloat16))
 
 
 def test_vmem_budget_is_device_derived(monkeypatch):
+    from repro.tune import device as D
     monkeypatch.delenv(BUDGET_ENV, raising=False)
     monkeypatch.delenv("REPRO_VMEM_BYTES", raising=False)
-    budget = fused_gather_budget_bytes()
-    assert 0 < budget < 16 * 1024 * 1024         # a fraction of VMEM, not 0
+    # the CPU models a v5e: 128 MiB VMEM and 1 MiB SMEM, as its compiler
+    # reports them
+    assert D.vmem_bytes() == 128 * 1024 * 1024
+    assert D.smem_bytes() == 1024 * 1024
+    assert fused_gather_budget_bytes() == D.vmem_bytes() // 4
     monkeypatch.setenv("REPRO_VMEM_BYTES", str(8 * 1024 * 1024))
     assert fused_gather_budget_bytes() == 2 * 1024 * 1024
+
+
+def test_unknown_tpu_kind_raises(monkeypatch):
+    """A TPU kind missing from the memory table is an error, not a
+    default size."""
+    import types
+    from repro.tune import device as D
+
+    def fake(kind):
+        return lambda: [types.SimpleNamespace(platform="tpu",
+                                              device_kind=kind)]
+    D.device_kind.cache_clear()
+    try:
+        monkeypatch.setattr(D.jax, "devices", fake("TPU v99"))
+        with pytest.raises(RuntimeError, match="unknown TPU kind"):
+            D.vmem_bytes()
+        D.device_kind.cache_clear()
+        monkeypatch.setattr(D.jax, "devices", fake("TPU v5 lite"))
+        assert D.device_kind() == "tpu:TPU v5 lite"
+        assert D.vmem_bytes() == 128 * 1024 * 1024
+    finally:
+        monkeypatch.undo()
+        D.device_kind.cache_clear()
 
 
 # ---------------------------------------------------------------------------
