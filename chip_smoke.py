@@ -266,7 +266,7 @@ def run_one_chip(backend: str = "pallas", dataset: str = GRAPH[0],
     out_ref = serve(ref, params, store, batches, "xla")
     out_ker = serve(ker, params, store, batches, backend)
     mb = batches[0]
-    lowered = jax.jit(ker.block_executor._traced).lower(
+    lowered = jax.jit(ker.block_executor.hector_blocks).lower(
         list(params), list(mb.tensors), list(mb.layouts),
         list(mb.dst_locals), mb.seed_perm,
         {"feature": jax.ShapeDtypeStruct((mb.input_ids.shape[0],

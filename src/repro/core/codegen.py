@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import re
 from typing import Dict, Optional
 
 import jax
@@ -208,6 +209,32 @@ def _elementwise(op: str, args, alpha: float = 0.01):
     raise ValueError(op)
 
 
+def _scope_part(name: str) -> str:
+    return re.sub(r"[^A-Za-z0-9_.-]", "_", name)
+
+
+def op_scope(op, layer: int = 0) -> str:
+    """The ``jax.named_scope`` an op spec's device work carries:
+    ``l<layer>.<kind>.<output>`` (``l1.traversal.h_out``). Compiled
+    instructions keep it in their ``op_name`` metadata, under
+    ``transpose(...)`` in the backward pass (``obs/device_ops.py``)."""
+    if isinstance(op, O.TraversalSpec):
+        kind, out = "traversal", op.stmts[-1].out
+    elif isinstance(op, O.GemmSpec):
+        kind, out = "gemm", op.out
+    elif isinstance(op, O.WeightProductSpec):
+        kind, out = "wprod", op.out
+    else:
+        kind, out = "fallback", op.kid
+    return f"l{layer}.{kind}.{_scope_part(out)}"
+
+
+def output_scope(plan: O.Plan, layer: int) -> str:
+    """Scope of a layer's output handling between layers (frontier
+    narrowing, activation, the final seed gather)."""
+    return f"l{layer}.output.{_scope_part(plan.outputs[0])}"
+
+
 def execute_plan(
     plan: O.Plan,
     params: Dict[str, jnp.ndarray],
@@ -216,30 +243,38 @@ def execute_plan(
     kl: KernelLayouts,
     backend: str = "xla",
     decisions=None,
+    layer: int = 0,
 ) -> Dict[str, jnp.ndarray]:
     """Run the lowered layer. Returns {output name: array}.
 
     ``decisions`` is an optional ``tune.TuningDecisions`` table; op
     instances found in it dispatch on the recorded variant (backend, tile
-    shape, gather fusion) instead of the hardcoded defaults.
+    shape, gather fusion) instead of the hardcoded defaults. ``layer``
+    names the ops' scopes (``op_scope``).
     """
     env = _Env(plan, gt, params, feats)
     derived: Dict[str, jnp.ndarray] = {}
     for op in plan.ops:
-        execute_op(op, env, derived, gt, kl, backend, decisions)
+        execute_op(op, env, derived, gt, kl, backend, decisions, layer)
     return {name: env.get(name) for name in plan.outputs}
 
 
 def execute_op(op, env: _Env, derived: Dict[str, jnp.ndarray],
                gt: GraphTensors, kl: KernelLayouts, backend: str = "xla",
-               decisions=None) -> None:
+               decisions=None, layer: int = 0) -> None:
     """Execute ONE lowered op spec against the environment — the loop body
     of ``execute_plan``, factored out so the obs profiler can advance a
-    plan op by op and time each instance individually.
+    plan op by op and time each instance individually. The op runs under
+    its ``op_scope``.
 
     ``derived`` carries hoisted weight products (``WeightProductSpec``
     outputs) that later GEMMs resolve before the parameter table.
     """
+    with jax.named_scope(op_scope(op, layer)):
+        _execute_op(op, env, derived, gt, kl, backend, decisions)
+
+
+def _execute_op(op, env, derived, gt, kl, backend, decisions) -> None:
     if isinstance(op, O.WeightProductSpec):
         wm, wv = env.params[op.w_matrix], env.params[op.w_vector]
         # (x W_r) · w_r == x (W_r w_r^T): hoisted weight-weight BMM
@@ -298,11 +333,14 @@ def execute_block_sequence(
     h = None
     last = len(plans) - 1
     for i, (plan, p, gt, kl) in enumerate(zip(plans, params, gts, kls)):
-        out = execute_plan(plan, p, gt, cur, kl, backend, decisions)
-        h = out[plan.outputs[0]][dst_locals[i]]
-        if i < last:
-            cur = {"feature": act(h)}
-    return h[seed_perm]
+        out = execute_plan(plan, p, gt, cur, kl, backend, decisions, i)
+        with jax.named_scope(output_scope(plan, i)):
+            h = out[plan.outputs[0]][dst_locals[i]]
+            if i < last:
+                cur = {"feature": act(h)}
+            else:
+                h = h[seed_perm]
+    return h
 
 
 # gather schemes whose row lists have a precomposed padded gather-index

@@ -15,6 +15,13 @@ executable per bucket: zero retraces, zero Python op dispatch. Cache hits,
 misses, and actual traces are counted so tests and the serve_cached
 benchmark can assert the steady state.
 
+Each executor jits a function named for what it runs (``hector_forward``,
+``hector_blocks``, ``hector_train_step``, ...), so a device profile names
+the program ``jit_<name>``. A cache entry keeps the compiled executable
+(``jit(...).lower(args).compile()``) and calls it; the compile records the
+optimized HLO's instruction-to-owner table (``obs/device_ops.py``) from
+that executable: no second trace, no second compile.
+
 The training steps donate the optimizer state on every backend, the CPU
 included: the new state has the old one's shapes, so the update runs in
 place, and the old state is deleted by the call. Input features are not
@@ -29,6 +36,7 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.core import codegen
+from repro.obs import device_ops
 
 
 def signature(args) -> tuple:
@@ -38,7 +46,10 @@ def signature(args) -> tuple:
     metadata), the leaves carry the bucketed array shapes — together exactly
     the information that determines the compiled executable.
     """
-    leaves, treedef = jax.tree_util.tree_flatten(args)
+    return _signature(*jax.tree_util.tree_flatten(args))
+
+
+def _signature(leaves, treedef) -> tuple:
     return treedef, tuple(
         (jnp.shape(l), jnp.result_type(l).name) for l in leaves)
 
@@ -70,9 +81,6 @@ class _CachedExecutor:
         fresh entries under its fingerprint."""
         self.decisions = decisions
 
-    def _traced(self, *args):
-        raise NotImplementedError
-
     def _count_trace(self) -> None:
         """Called from inside the traced functions: counts actual
         (re)traces. Runs at trace time on the host — never inside the
@@ -81,22 +89,33 @@ class _CachedExecutor:
         obs.metrics().counter("executor_traces",
                               executor=type(self).__name__).inc()
 
-    def _call(self, *args):
+    def _call(self, program, *args):
+        """Run ``program`` (the executor's one traced function) on
+        ``args``. A cache entry holds its ``jit`` and, from the first call
+        on concrete arrays, the executable compiled for their signature;
+        a call under a transformation (``jax.grad`` around the executor)
+        goes through the ``jit``."""
         fp = self.decisions.fingerprint() if self.decisions is not None \
             else None
-        key = (self._static_key, fp) + signature(args)
-        fn = self._cache.get(key)
-        if fn is None:
+        leaves, treedef = jax.tree_util.tree_flatten(args)
+        key = (self._static_key, fp) + _signature(leaves, treedef)
+        entry = self._cache.get(key)
+        if entry is None:
             self.cache_misses += 1
             obs.metrics().counter("executor_cache_misses",
                                   executor=type(self).__name__).inc()
-            fn = jax.jit(self._traced, donate_argnums=self._donate_argnums)
-            self._cache[key] = fn
+            entry = self._cache[key] = [
+                jax.jit(program, donate_argnums=self._donate_argnums), None]
         else:
             self.cache_hits += 1
             obs.metrics().counter("executor_cache_hits",
                                   executor=type(self).__name__).inc()
-        return fn(*args)
+        if any(isinstance(l, jax.core.Tracer) for l in leaves):
+            return entry[0](*args)
+        if entry[1] is None:
+            entry[1] = entry[0].lower(*args).compile()
+            device_ops.record(entry[1])
+        return entry[1](*args)
 
     @property
     def num_compiled(self) -> int:
@@ -119,19 +138,21 @@ class PlanExecutor(_CachedExecutor):
     distinct cache entries.
     """
 
-    def __init__(self, plan, backend: str = "xla", decisions=None):
+    def __init__(self, plan, backend: str = "xla", decisions=None,
+                 layer: int = 0):
         super().__init__(decisions=decisions,
                          static_key=(plan.fingerprint(),))
         self.plan = plan
         self.backend = backend
+        self.layer = layer         # the plan's place in its stack (scopes)
 
-    def _traced(self, params, gt, kl, feats):
+    def hector_forward(self, params, gt, kl, feats):
         self._count_trace()
         return codegen.execute_plan(self.plan, params, gt, feats, kl,
-                                    self.backend, self.decisions)
+                                    self.backend, self.decisions, self.layer)
 
     def __call__(self, params, gt, kl, feats) -> Dict[str, jnp.ndarray]:
-        return self._call(params, gt, kl, feats)
+        return self._call(self.hector_forward, params, gt, kl, feats)
 
 
 class BlockExecutor(_CachedExecutor):
@@ -151,7 +172,7 @@ class BlockExecutor(_CachedExecutor):
         self.backend = backend
         self.activation = activation
 
-    def _traced(self, params, gts, kls, dst_locals, seed_perm, feats):
+    def hector_blocks(self, params, gts, kls, dst_locals, seed_perm, feats):
         self._count_trace()
         return codegen.execute_block_sequence(
             self.plans, params, gts, kls, dst_locals, seed_perm, feats,
@@ -161,8 +182,8 @@ class BlockExecutor(_CachedExecutor):
     def __call__(self, params: Sequence[Dict[str, jnp.ndarray]],
                  gts: List, kls: List, dst_locals: List,
                  seed_perm, feats: Dict[str, jnp.ndarray]) -> jnp.ndarray:
-        return self._call(list(params), list(gts), list(kls),
-                          list(dst_locals), seed_perm, feats)
+        return self._call(self.hector_blocks, list(params), list(gts),
+                          list(kls), list(dst_locals), seed_perm, feats)
 
     def run_minibatch(self, params, mb, global_feats=None, *,
                       feats=None) -> jnp.ndarray:
@@ -216,7 +237,8 @@ class BlockTrainExecutor(_CachedExecutor):
         self.backend = backend
         self.activation = activation
 
-    def _traced(self, state, gts, kls, dst_locals, seed_perm, labels, feats):
+    def hector_block_train_step(self, state, gts, kls, dst_locals,
+                                seed_perm, labels, feats):
         self._count_trace()
 
         def loss_fn(params):
@@ -224,7 +246,8 @@ class BlockTrainExecutor(_CachedExecutor):
                 self.plans, params, gts, kls, dst_locals, seed_perm, feats,
                 backend=self.backend, activation=self.activation,
                 decisions=self.decisions)
-            return softmax_xent(logits, labels)
+            with jax.named_scope("loss"):
+                return softmax_xent(logits, labels)
 
         (loss, acc), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
@@ -239,7 +262,8 @@ class BlockTrainExecutor(_CachedExecutor):
         feature dict for the first block's node set. Returns
         ``(new_state, {"loss", "accuracy"})``.
         """
-        return self._call(state, list(mb.tensors), list(mb.layouts),
+        return self._call(self.hector_block_train_step, state,
+                          list(mb.tensors), list(mb.layouts),
                           list(mb.dst_locals), mb.seed_perm, labels, feats)
 
 
@@ -271,18 +295,20 @@ class StackTrainExecutor(_CachedExecutor):
         last = len(self.plans) - 1
         for i, (plan, p) in enumerate(zip(self.plans, params)):
             out = codegen.execute_plan(plan, p, gt, cur, kl, self.backend,
-                                       self.decisions)
-            h = out[plan.outputs[0]]
-            if i < last:
-                cur = {"feature": act(h)}
+                                       self.decisions, i)
+            with jax.named_scope(codegen.output_scope(plan, i)):
+                h = out[plan.outputs[0]]
+                if i < last:
+                    cur = {"feature": act(h)}
         return h
 
-    def _traced(self, state, gt, kl, idx, labels, feats):
+    def hector_train_step(self, state, gt, kl, idx, labels, feats):
         self._count_trace()
 
         def loss_fn(params):
             h = self._forward(params, gt, kl, feats)
-            return softmax_xent(h[idx], labels)
+            with jax.named_scope("loss"):
+                return softmax_xent(h[idx], labels)
 
         (loss, acc), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
@@ -292,21 +318,23 @@ class StackTrainExecutor(_CachedExecutor):
     def grad_and_update(self, state, gt, kl, idx, labels, feats):
         """One full-graph optimizer step; loss is taken over the ``idx``
         node rows (the training split)."""
-        return self._call(state, gt, kl, idx, labels, feats)
+        return self._call(self.hector_train_step, state, gt, kl, idx, labels,
+                          feats)
 
     def set_decisions(self, decisions) -> None:
         super().set_decisions(decisions)
         self._eval_fn = None   # compiled under the old decision table
 
     # -- compiled evaluation (no update) ---------------------------------
-    def _traced_eval(self, params, gt, kl, idx, labels, feats):
+    def hector_eval(self, params, gt, kl, idx, labels, feats):
         h = self._forward(params, gt, kl, feats)
-        return softmax_xent(h[idx], labels)
+        with jax.named_scope("loss"):
+            return softmax_xent(h[idx], labels)
 
     def evaluate(self, params, gt, kl, idx, labels, feats):
         """Full-graph loss/accuracy on the ``idx`` rows (jitted once —
         full-graph shapes are static)."""
         if self._eval_fn is None:
-            self._eval_fn = jax.jit(self._traced_eval)
+            self._eval_fn = jax.jit(self.hector_eval)
         loss, acc = self._eval_fn(params, gt, kl, idx, labels, feats)
         return {"loss": loss, "accuracy": acc}

@@ -39,6 +39,7 @@ class HectorModule:
         gt=None,
         layouts: Optional[codegen.KernelLayouts] = None,
         decisions=None,
+        layer: int = 0,
     ):
         self.program = program
         self.graph = graph
@@ -55,10 +56,12 @@ class HectorModule:
                                          node_block=node_block)
         self.backend = backend
         self.decisions = decisions
+        self.layer = layer         # place in its stack: names the op scopes
         # whole-plan compiled executor: graph tensors and layouts flow in as
         # pytree arguments, fronted by an explicit compile cache
         self.executor = executor.PlanExecutor(
-            self.plan, backend=backend, decisions=decisions) if jit else None
+            self.plan, backend=backend, decisions=decisions,
+            layer=layer) if jit else None
 
     # ------------------------------------------------------------------
     def init(self, key: jax.Array, dtype=jnp.float32) -> Dict[str, jnp.ndarray]:
@@ -69,7 +72,7 @@ class HectorModule:
             return self.executor(params, self.gt, self.layouts, feats)
         return codegen.execute_plan(
             self.plan, params, self.gt, feats, self.layouts, self.backend,
-            self.decisions
+            self.decisions, self.layer
         )
 
     def describe(self) -> str:
@@ -126,7 +129,7 @@ class HectorStack:
                                        else compact_vars[i]),
                          backend=backend, tile=tile, node_block=node_block,
                          jit=jit, gt=gt, layouts=layouts,
-                         decisions=decisions)
+                         decisions=decisions, layer=i)
             for i, p in enumerate(programs)
         ]
         self.activation = activation

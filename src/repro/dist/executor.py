@@ -101,8 +101,8 @@ class ShardedServeExecutor(_ShardedExecutor):
         super().__init__(plans, mesh, backend, activation,
                          decisions=decisions, tag="serve")
 
-    def _traced(self, params, own_feats, gts, kls, dstl, perm, orow, lrow,
-                route):
+    def hector_sharded_blocks(self, params, own_feats, gts, kls, dstl, perm,
+                              orow, lrow, route):
         self._count_trace()
 
         def body(params, own_feats, gts, kls, dstl, perm, orow, lrow):
@@ -131,7 +131,8 @@ class ShardedServeExecutor(_ShardedExecutor):
             (own_feats, list(smb.tensors), list(smb.layouts),
              list(smb.dst_locals), smb.seed_perm, smb.owner_rows,
              smb.local_rows))
-        return self._call(params, *sharded, route)
+        return self._call(self.hector_sharded_blocks, params, *sharded,
+                          route)
 
 
 class ShardedTrainExecutor(_ShardedExecutor):
@@ -146,8 +147,9 @@ class ShardedTrainExecutor(_ShardedExecutor):
                          tag="train")
         self.opt = opt
 
-    def _traced(self, state, own_feats, gts, kls, dstl, perm, orow, lrow,
-                labels, mask, route, inv_b):
+    def hector_sharded_train_step(self, state, own_feats, gts, kls, dstl,
+                                  perm, orow, lrow, labels, mask, route,
+                                  inv_b):
         self._count_trace()
 
         def body(params, own_feats, gts, kls, dstl, perm, orow, lrow,
@@ -161,10 +163,11 @@ class ShardedTrainExecutor(_ShardedExecutor):
                 def loss_fn(p):
                     logits = self._forward_one(
                         p, full_feats, (gts, kls, dstl, perm, orow, lrow))
-                    logp = jax.nn.log_softmax(logits)
-                    nll = -jnp.take_along_axis(
-                        logp, labels[:, None], axis=1)[:, 0]
-                    return jnp.sum(nll * mask) * inv_b, (nll, logits)
+                    with jax.named_scope("loss"):
+                        logp = jax.nn.log_softmax(logits)
+                        nll = -jnp.take_along_axis(
+                            logp, labels[:, None], axis=1)[:, 0]
+                        return jnp.sum(nll * mask) * inv_b, (nll, logits)
 
                 (_, (nll, logits)), g = jax.value_and_grad(
                     loss_fn, has_aux=True)(params)
@@ -214,7 +217,8 @@ class ShardedTrainExecutor(_ShardedExecutor):
             (own_feats, list(smb.tensors), list(smb.layouts),
              list(smb.dst_locals), smb.seed_perm, smb.owner_rows,
              smb.local_rows, smb.slice_labels(labels), smb.mask))
-        return self._call(state, *sharded, route, inv_b)
+        return self._call(self.hector_sharded_train_step, state, *sharded,
+                          route, inv_b)
 
     def lowered_hlo(self, state, smb, labels, own_feats) -> str:
         """Lowered (StableHLO) text of the whole train step for these
@@ -225,7 +229,7 @@ class ShardedTrainExecutor(_ShardedExecutor):
         cache."""
         _num_local(self.mesh, smb.num_shards)
         inv_b = jnp.float32(1.0 / len(smb.seeds))
-        return jax.jit(self._traced).lower(
+        return jax.jit(self.hector_sharded_train_step).lower(
             state, own_feats, list(smb.tensors), list(smb.layouts),
             list(smb.dst_locals), smb.seed_perm, smb.owner_rows,
             smb.local_rows, smb.slice_labels(labels), smb.mask, smb.route,

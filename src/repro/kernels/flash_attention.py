@@ -107,5 +107,6 @@ def flash_attention(
         out_specs=pl.BlockSpec((1, q_tile, 1, hd),
                                lambda bi, hi, qi: (bi, qi, hi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, sq, h, hd), q.dtype),
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)

@@ -89,6 +89,7 @@ def _candidate_keys_pallas(starts2, cnts2, base_arr, *, width, interpret):
             out_specs=pl.BlockSpec((tile_rows, width), lambda i, base: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((rows, width), jnp.uint32),
+        name="_candidate_keys_pallas",
         interpret=interpret,
     )(base_arr, starts2, cnts2)
 

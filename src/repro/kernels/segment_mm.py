@@ -82,6 +82,7 @@ def segment_mm_padded(
             out_specs=pl.BlockSpec((tile_rows, tile_n), lambda i, j, t2g: (i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((rp, n), x_p.dtype),
+        name="segment_mm_padded",
         interpret=interpret,
     )(t2g, *args)
 
@@ -157,6 +158,7 @@ def segment_mm_gather_padded(
             scratch_shapes=[pltpu.VMEM((tile_rows, k), x.dtype)],
         ),
         out_shape=jax.ShapeDtypeStruct((rp, n), x.dtype),
+        name="segment_mm_gather_padded",
         interpret=interpret,
     )(gidx, t2g, *args)
 
@@ -211,5 +213,6 @@ def segment_outer_padded(
             out_specs=pl.BlockSpec((1, k, n), lambda t, meta: (meta[0, t], 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((num_groups, k, n), jnp.float32),
+        name="segment_outer_padded",
         interpret=interpret,
     )(meta, x_p, dy_p)

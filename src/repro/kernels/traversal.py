@@ -164,6 +164,7 @@ def seg_stats_padded(
             out_specs=[_node_spec(node_block, 1), _node_spec(node_block, 1)],
         ),
         out_shape=[stat, stat],
+        name="seg_stats_padded",
         interpret=interpret,
     )(_block_meta(t2b), scores_p.reshape(num_tiles, 1, tile),
       local_dst_p.reshape(num_tiles, 1, tile))
@@ -210,6 +211,7 @@ def seg_softmax_agg_padded(
         ),
         out_shape=jax.ShapeDtypeStruct((num_node_blocks * node_block, d),
                                        msg_p.dtype),
+        name="seg_softmax_agg_padded",
         interpret=interpret,
     )(_block_meta(t2b), scores_p.reshape(num_tiles, 1, tile),
       local_dst_p.reshape(num_tiles, 1, tile), msg_p, mx, den)
@@ -263,6 +265,7 @@ def seg_softmax_agg_gather_padded(
         ),
         out_shape=jax.ShapeDtypeStruct((num_node_blocks * node_block, d),
                                        msg.dtype),
+        name="seg_softmax_agg_gather_padded",
         interpret=interpret,
     )(mmap, _block_meta(t2b), scores_p.reshape(num_tiles, 1, tile),
       local_dst_p.reshape(num_tiles, 1, tile), msg, mx, den)
@@ -309,6 +312,7 @@ def seg_weighted_agg_gather_padded(
         ),
         out_shape=jax.ShapeDtypeStruct((num_node_blocks * node_block, d),
                                        msg.dtype),
+        name="seg_weighted_agg_gather_padded",
         interpret=interpret,
     )(mmap, _block_meta(t2b), scale_p.reshape(num_tiles, 1, tile),
       local_dst_p.reshape(num_tiles, 1, tile), msg)
@@ -350,6 +354,7 @@ def seg_weighted_agg_padded(
         ),
         out_shape=jax.ShapeDtypeStruct((num_node_blocks * node_block, d),
                                        msg_p.dtype),
+        name="seg_weighted_agg_padded",
         interpret=interpret,
     )(_block_meta(t2b), scale_p.reshape(num_tiles, 1, tile),
       local_dst_p.reshape(num_tiles, 1, tile), msg_p)

@@ -6,17 +6,22 @@ Three layers, one switchboard:
   with p50/p90/p99, labeled, JSON-exportable — ``obs.metrics()`` is the one
   handle every component reports through.
 * **tracing** (``tracing.py``): nested ``with obs.span("sample")`` phase
-  spans with host wall clock and explicit device sync points, exported as
-  a Chrome-trace JSON plus per-phase time tables.
+  spans. Each is a ``jax.profiler.TraceAnnotation``, on the profiler's
+  clock in any capture; inside a tracing scope it is also kept with its
+  host wall clock, exported as a Chrome-trace JSON plus per-phase time
+  tables. Spans never sync the device.
+* **device table** (``device_ops.py``): each executor compile records which
+  IR op (``l1.traversal.h_out``), the loss or the optimizer owns every
+  instruction of the optimized program, forward or backward, so device
+  time in a profile reads per model op.
 * **profiling** (``profile.py``, imported lazily): per-op plan timing on
   the tuner's measurement harness — ``CompiledRGNN.profile()`` and the
   drivers' ``--profile`` flag.
 
-The switchboard is **off by default and zero-overhead when off**: every
-``obs.span(...)`` returns a shared no-op span and ``obs.metrics()`` the
-shared null registry, so instrumented library code costs one attribute
-read per event. Nothing here ever runs inside jitted code — enabling or
-disabling observability cannot change trace behavior or compiled shapes.
+The switchboard is **off by default and near free when off**:
+``obs.metrics()`` returns the shared null registry, and ``obs.span(...)``
+the shared no-op span unless a profiler is recording. Nothing here ever runs inside jitted code — enabling or disabling
+observability cannot change trace behavior or compiled shapes.
 
 Drivers opt in with a scope::
 
@@ -33,6 +38,8 @@ from __future__ import annotations
 
 import contextlib
 from typing import Iterator, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.registry import (Counter, Gauge, Histogram, MetricsRegistry,
                                 NULL_REGISTRY, SCHEMA_VERSION)
@@ -93,12 +100,17 @@ def tracer() -> Optional[SpanTracer]:
 
 
 def span(name: str, **args):
-    """A phase span context manager; the shared no-op span when tracing is
-    disabled (one attribute read, no allocation)."""
+    """A phase span context manager: a ``TraceAnnotation`` that a running
+    profiler records, also kept by the scope's tracer when tracing is on;
+    the shared no-op span when neither records."""
     st = _current
-    if not st.tracing_on:
-        return NULL_SPAN
-    return st.tracer.span(name, **args)
+    if st.tracing_on:
+        return st.tracer.span(name, **args)
+    # building an annotation that records nothing costs several times
+    # this check
+    if TraceAnnotation.is_enabled():
+        return TraceAnnotation(name, **args)
+    return NULL_SPAN
 
 
 @contextlib.contextmanager

@@ -1,23 +1,19 @@
 """Phase-attributed span tracer with Chrome-trace export.
 
-``with obs.span("sample"): ...`` records a host-side wall-clock interval,
-attributed to the thread that opened it — so the prefetch loader's
-``sample``/``layout`` spans land on their own track next to the driver
-thread's ``execute`` spans, and ``chrome://tracing`` / Perfetto render the
-overlap directly.
+``with obs.span("sample"): ...`` opens a ``jax.profiler.TraceAnnotation``,
+which a running profiler records on the host plane of its capture, on the
+same clock as the device ops. When an obs scope has tracing on, the span is
+also recorded here as a host wall-clock interval, attributed to the thread
+that opened it — so the prefetch loader's ``sample``/``layout`` spans land
+on their own track next to the driver thread's ``execute`` spans, and
+``chrome://tracing`` / Perfetto render the overlap directly.
 
 Accelerator work is asynchronous, so a span around a dispatched computation
-measures dispatch only; spans that should cover device time must end at an
-explicit sync point. ``Span.sync(x)`` calls ``jax.block_until_ready`` on
-``x`` *inside* the span (and is a no-op passthrough on the disabled-mode
-null span, so instrumented code behaves identically either way)::
+measures dispatch only. Spans never sync: device time is read from a
+profiler capture, where the device ops sit beside the spans, so turning
+tracing on leaves the program as it runs with tracing off.
 
-    with obs.span("execute") as sp:
-        logits = executor(params, ...)
-        sp.sync(logits)          # device time charged to the span
-
-Spans never run inside compiled code — the tracer is pure host-side Python
-with no jax imports on the hot path — so enabling tracing cannot perturb
+Spans never run inside compiled code, so enabling tracing cannot perturb
 jit caches or introduce retraces.
 """
 from __future__ import annotations
@@ -27,12 +23,14 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 
 class Span:
     """One open interval. Context manager; reentrant use is not supported
     (open a new span instead)."""
 
-    __slots__ = ("tracer", "name", "args", "t0", "_depth")
+    __slots__ = ("tracer", "name", "args", "t0", "_depth", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str, args: dict):
         self.tracer = tracer
@@ -40,8 +38,12 @@ class Span:
         self.args = args
         self.t0 = 0.0
         self._depth = 0
+        self._ann = None
 
     def __enter__(self) -> "Span":
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(self.name, **self.args)
+            self._ann.__enter__()
         self._depth = self.tracer._push(self.name)
         self.t0 = time.perf_counter()
         return self
@@ -50,18 +52,14 @@ class Span:
         t1 = time.perf_counter()
         self.tracer._pop()
         self.tracer._record(self.name, self.t0, t1, self._depth, self.args)
-
-    def sync(self, x):
-        """Block until ``x``'s device computation is done, charging the
-        wait to this span; returns ``x``."""
-        import jax
-        return jax.block_until_ready(x)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
 
 
 class _NullSpan:
-    """Disabled-mode span: free to enter/exit, records nothing. ``sync``
-    is a passthrough (no implicit device sync in disabled mode — callers
-    that need the result synced already block on it themselves)."""
+    """Span with nothing to record (no tracing scope, no profiler): free
+    to enter/exit."""
 
     __slots__ = ()
 
@@ -70,9 +68,6 @@ class _NullSpan:
 
     def __exit__(self, *exc) -> None:
         pass
-
-    def sync(self, x):
-        return x
 
 
 NULL_SPAN = _NullSpan()

@@ -47,6 +47,7 @@ class AdamW:
             return self.learning_rate(step)
         return jnp.float32(self.learning_rate)
 
+    @jax.named_scope("optimizer")
     def update(self, grads, state: TrainState) -> TrainState:
         step = state.step + 1
         if self.clip_norm is not None:

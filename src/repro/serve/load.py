@@ -166,11 +166,14 @@ class OpenLoopLoad:
         return out
 
     # ------------------------------------------------------------------
-    def replay(self, submit: Callable[[Request], object],
+    def replay(self, submit: Callable[[Request, float], object],
                requests: Optional[List[Request]] = None,
                speedup: float = 1.0) -> int:
         """Submit the schedule in real time (open loop: never waits on
-        completions). ``speedup`` > 1 compresses the schedule. Returns the
+        completions). Each request goes to ``submit(req, due)`` with the
+        ``time.monotonic`` time it was due, the clock ``ServingRuntime``
+        keeps by default, so a submission that runs late is charged to the
+        request. ``speedup`` > 1 compresses the schedule. Returns the
         number of requests submitted; stops early (without raising) on
         KeyboardInterrupt so the caller can drain what is in flight."""
         if requests is None:
@@ -179,10 +182,11 @@ class OpenLoopLoad:
         submitted = 0
         try:
             for req in requests:
-                delay = t0 + req.arrival_s / speedup - time.monotonic()
+                due = t0 + req.arrival_s / speedup
+                delay = due - time.monotonic()
                 if delay > 0:
                     time.sleep(delay)
-                submit(req)
+                submit(req, due)
                 submitted += 1
         except KeyboardInterrupt:
             pass

@@ -337,11 +337,15 @@ class ServingRuntime:
     # ------------------------------------------------------------------
     # submission
     # ------------------------------------------------------------------
-    def submit(self, req: Request) -> _Handle:
-        """Admit ``req`` into the queue (stamping its arrival time).
-        Returns a completion handle; rejections resolve it immediately."""
+    def submit(self, req: Request, due: Optional[float] = None) -> _Handle:
+        """Admit ``req`` into the queue, stamping its arrival time: ``due``,
+        the time the request was due on the runtime's clock, when given
+        (a load generator running late then counts its delay in the
+        request's queueing and latency), else now. Returns a completion
+        handle; rejections resolve it immediately."""
         handle = _Handle()
-        req.t_arrive = self._now()
+        now = self._now()
+        req.t_arrive = now if due is None else min(due, now)
         if req.num_seeds > self.coalescer.max_rung:
             raise ValueError(
                 f"request {req.rid}: {req.num_seeds} seeds exceed the top "

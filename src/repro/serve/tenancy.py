@@ -106,9 +106,9 @@ class MultiTenantRuntime:
     # ------------------------------------------------------------------
     # routing
     # ------------------------------------------------------------------
-    def submit(self, req: Request):
+    def submit(self, req: Request, due: Optional[float] = None):
         """Dispatch on ``req.model``; a single-tenant deployment may leave
-        it unset."""
+        it unset. ``due`` as in ``ServingRuntime.submit``."""
         if req.model is None:
             if len(self._tenants) != 1:
                 raise ValueError(
@@ -121,7 +121,7 @@ class MultiTenantRuntime:
                 raise KeyError(
                     f"request {req.rid}: unknown model {req.model!r} "
                     f"(tenants: {sorted(self._tenants)})")
-        return rt.submit(req)
+        return rt.submit(req, due)
 
     # ------------------------------------------------------------------
     # reporting

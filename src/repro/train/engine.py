@@ -412,13 +412,12 @@ class RGNNEngine:
         ``repro.feats`` store; loader-attached ``mb.feats`` win either
         way (the prefetch overlap already paid for that gather)."""
         from repro.feats import gather_input
-        with obs.span("execute", step=mb.step) as sp:
-            out = self.stack.apply_blocks(
+        with obs.span("execute", step=mb.step):
+            return self.stack.apply_blocks(
                 params, mb, compiled=compiled,
                 feats=gather_input(global_feats, mb))
-            return sp.sync(out)
 
     def forward_full(self, params, feats: jnp.ndarray) -> jnp.ndarray:
         """Full-graph forward (compiled per layer via ``PlanExecutor``)."""
-        with obs.span("execute", mode="full_graph") as sp:
-            return sp.sync(self.stack.apply(params, {"feature": feats}))
+        with obs.span("execute", mode="full_graph"):
+            return self.stack.apply(params, {"feature": feats})

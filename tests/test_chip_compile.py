@@ -139,3 +139,51 @@ def test_fusion_gate_limits_compile(one_chip):
     fn = KERNELS["seg_weighted_agg_gather_padded"][0]
     _compile(fn, one_chip, ((tiles, TILE), F32), ((rows, D_FEAT), F32),
              ((slots,), I32), ((tiles, TILE), I32), ((tiles,), I32))
+
+
+def test_train_step_instructions_have_owners_for_v5e(one_chip):
+    """A small full-graph RGAT training step compiled for the v5e: every
+    fusion and custom call has an owner in the model (``obs/device_ops``),
+    and every Pallas call is named after its wrapper, as the roofline
+    readers' ``kernel_names.json`` expects."""
+    import json
+    import pathlib
+    from repro.core.graph import synthetic_heterograph
+    from repro.obs import device_ops
+    from repro.optim import AdamW
+    from repro.train import EngineConfig, FullGraphTrainer, RGNNEngine
+    graph = synthetic_heterograph(num_nodes=400, num_edges=1200,
+                                  num_ntypes=3, num_etypes=12, seed=0)
+    eng = RGNNEngine(graph, EngineConfig(
+        model="rgat", layers=2, dim=D_FEAT, hidden=D_FEAT, classes=11,
+        backend="pallas", tile=TILE, node_block=NB, seed=0))
+    feats = jnp.zeros((graph.num_nodes, D_FEAT), F32)
+    tr = FullGraphTrainer(eng, feats, np.zeros(graph.num_nodes, np.int32),
+                          np.arange(graph.num_nodes), opt=AdamW(), log=None)
+    state = tr.init_state(eng.init_params(jax.random.key(0)))
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a),
+                                       sharding=one_chip),
+        (state, eng.gt, eng.layouts, tr._idx, tr._labels_train,
+         {"feature": tr.feats}))
+    text = jax.jit(tr.step_exec.hector_train_step).lower(
+        *args).compile().as_text()
+    module, table = device_ops.parse_hlo(text)
+    assert module == "jit_hector_train_step"
+    unowned_ok = set()      # none at this size
+    kernels = [p for ps in json.loads(
+        (pathlib.Path(__file__).parents[1] / "bench" / "metrics" /
+         "kernel_names.json").read_text()).values() for p in ps]
+    seen = 0
+    for line in text.splitlines():
+        name = line.split("=")[0].split()[-1].lstrip("%") if "=" in line \
+            else None
+        if name not in table or not (" fusion(" in line
+                                     or " custom-call(" in line):
+            continue
+        seen += 1
+        assert table[name] is not None or name in unowned_ok, line[:200]
+        if "tpu_custom_call" in line:
+            assert any(name.startswith(k + ".") or name == k
+                       for k in kernels), name
+    assert seen > 50

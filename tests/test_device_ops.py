@@ -1,0 +1,117 @@
+"""Device work named after the model: every instruction of a compiled
+training step gets an owner (an IR op's scope, the loss or the optimizer)
+and a direction from the program's own scopes (``obs/device_ops.py``), and
+the executors name their programs for what they run."""
+import re
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+from repro.core import codegen
+from repro.core.graph import synthetic_heterograph
+from repro.obs import device_ops
+from repro.optim import AdamW
+from repro.train import EngineConfig, FullGraphTrainer, RGNNEngine
+
+_LINE = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\S*)\s.*?"
+                   r"([a-z][\w\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+@pytest.fixture(scope="module")
+def graph():
+    return synthetic_heterograph(num_nodes=120, num_edges=900, num_ntypes=4,
+                                 num_etypes=7, seed=0)
+
+
+def _train_step_hlo(graph, model):
+    """One step of a tiny full-graph trainer on the Pallas kernels
+    (interpret mode); returns the step's optimized HLO text and the number
+    of unique (source, relation) pairs, the rows of a compact message."""
+    eng = RGNNEngine(graph, EngineConfig(
+        model=model, layers=2, dim=16, hidden=12, classes=6, fanouts=[3, 3],
+        backend="pallas_interpret", tile=8, node_block=8, seed=0))
+    rng = np.random.default_rng(1)
+    feats = jnp.asarray(rng.normal(size=(graph.num_nodes, 16)), jnp.float32)
+    labels = rng.integers(0, 6, graph.num_nodes)
+    tr = FullGraphTrainer(eng, feats, labels, np.arange(graph.num_nodes),
+                          opt=AdamW(learning_rate=1e-2), log=None)
+    tr.step(tr.init_state(eng.init_params(jax.random.key(0))))
+    (_, compiled), = tr.step_exec._cache.values()
+    return compiled.as_text(), int(eng.gt.unique_src.shape[0])
+
+
+def _instructions(text):
+    """(name, result type, opcode, owner parsed from its own metadata) of
+    every instruction, fused ones included."""
+    out = []
+    for line in text.splitlines():
+        m = _LINE.match(line)
+        if m:
+            op = _OP_NAME.search(line)
+            out.append((m.group(1), m.group(2), m.group(3),
+                        device_ops.parse_op_name(op.group(1)) if op
+                        else None))
+    return out
+
+
+@pytest.mark.parametrize("model", ["rgat", "rgcn"])
+def test_train_step_instructions_have_owners(graph, model):
+    text, unique_pairs = _train_step_hlo(graph, model)
+    module, table = device_ops.parse_hlo(text)
+    assert module == "jit_hector_train_step"
+    assert device_ops.table(module) == table      # recorded at compile
+    instrs = _instructions(text)
+    for name, _, opcode, own in instrs:
+        if opcode in ("dot", "scatter", "custom-call"):
+            assert own is not None, (name, opcode)
+            assert device_ops.OWNERS.match(own.owner), own
+    owners = {o.owner for o in table.values() if o is not None}
+    assert {"loss", "optimizer"} <= owners
+    for prefix in ("l0.gemm.", "l1.gemm.", "l1.traversal."):
+        assert any(o.startswith(prefix) for o in owners), prefix
+    # the gradient scatter of each layer's compact messages, written in the
+    # gather-fused traversal kernel's backward (``kernels/ops.py``,
+    # ``dmsg = zeros_like(msg).at[msg_rows].add``): a [unique pairs, width]
+    # scatter-add owned by that layer's traversal op, backward
+    for layer, width in ((0, 12), (1, 6)):
+        want = f"f32[{unique_pairs},{width}]"
+        owned = {(o.owner.split(".")[:2] == [f"l{layer}", "traversal"],
+                  o.direction, o.inner.split("/")[-1])
+                 for _, rtype, opcode, o in instrs
+                 if opcode == "scatter" and rtype.startswith(want)}
+        assert owned == {(True, "backward", "scatter-add")}, (layer, owned)
+
+
+def test_scope_names_never_match_a_kernel_name(graph):
+    """The roofline readers match kernel names against instruction names;
+    no op scope may carry one."""
+    import json
+    import pathlib
+    names = json.loads((pathlib.Path(__file__).parents[1] / "bench" /
+                        "metrics" / "kernel_names.json").read_text())
+    patterns = [re.compile(p) for ps in names.values() for p in ps]
+    for model in ("rgat", "rgcn", "hgt", "rgcn_cat"):
+        eng = RGNNEngine(graph, EngineConfig(model=model, layers=2, dim=8,
+                                             hidden=8, classes=4))
+        for i, plan in enumerate(eng.plans):
+            scopes = [codegen.op_scope(op, i) for op in plan.ops]
+            scopes.append(codegen.output_scope(plan, i))
+            for s in scopes:
+                assert device_ops.OWNERS.match(s), s
+                assert not any(p.search(s) for p in patterns), s
+
+
+def test_op_name_parsing():
+    p = device_ops.parse_op_name
+    assert p("jit(hector_train_step)/transpose(jvp(loss))/"
+             "transpose(jvp(jit(take_along_axis)))/scatter-add") == \
+        device_ops.Owner("loss", "backward", "take_along_axis/scatter-add")
+    # a primitive named transpose is not the transpose transform
+    assert p("jit(f)/jvp(l0.gemm.hs)/transpose").direction == "forward"
+    assert p("jit(f)/optimizer/mul").owner == "optimizer"
+    assert p("jit(f)/jvp(jit(seg_stats_padded))/while") is None
+    assert device_ops.instruction_name(
+        "%fusion.55 = f32[3231104,64]{0,1:T(8,128)}") == "fusion.55"

@@ -227,6 +227,28 @@ def test_tracer_bounded_drops_not_grows():
     assert tr.num_events == 2 and tr.dropped == 3
 
 
+def test_spans_on_the_profiler_clock(tmp_path):
+    """A span is a ``TraceAnnotation``: a profiler capture holds it on the
+    host plane with its arguments, with no obs scope open, and a tracing
+    scope keeps it as well. With no profiler it is the no-op span again."""
+    from jax.profiler import ProfileData
+    x = jnp.ones(4)
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("x", step=3):
+            (x * 2).block_until_ready()
+        with obs.scope(metrics=False, tracing=True) as sc:
+            with obs.span("y", step=4):
+                pass
+    path, = tmp_path.glob("**/*.xplane.pb")
+    host = {ev.name: dict(ev.stats)
+            for pl in ProfileData.from_file(str(path)).planes
+            if pl.name.startswith("/host") for ln in pl.lines
+            for ev in ln.events}
+    assert host["x"] == {"step": 3} and host["y"] == {"step": 4}
+    assert len(sc.tracer.events("y")) == 1
+    assert obs.span("x", step=3) is NULL_SPAN
+
+
 # ---------------------------------------------------------------------------
 # profiler: telescoping-sum invariant on a real compiled model
 # ---------------------------------------------------------------------------

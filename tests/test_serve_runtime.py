@@ -218,6 +218,31 @@ def test_runtime_response_sizes_match_requests(served):
         rt.close()
 
 
+def test_runtime_stamps_arrival_at_the_due_time(served):
+    """A request submitted late counts the lateness: arrival is stamped at
+    the due time, so it shows in queueing and latency; ``replay`` hands
+    each request's due time over."""
+    rt = _runtime(served)
+    try:
+        _calibrate(rt)
+        late_s = 0.25
+        due = time.monotonic() - late_s
+        h = rt.submit(Request(rid=0, seeds=np.arange(2, dtype=np.int32),
+                              arrival_s=0.0, slo_ms=30_000.0), due)
+        resp = h.wait(timeout=10.0)
+        assert resp.status == OK
+        assert resp.queue_ms >= late_s * 1e3
+        assert resp.latency_ms >= resp.queue_ms
+    finally:
+        rt.close()
+    seen = []
+    load = OpenLoopLoad(160, rate_rps=2000.0, num_requests=4, seed=3)
+    t0 = time.monotonic()
+    assert load.replay(lambda r, d: seen.append((r, d))) == 4
+    for r, d in seen:
+        assert d == pytest.approx(t0 + r.arrival_s, abs=0.05)
+
+
 def test_runtime_rejects_unmeetable_deadline(served):
     rt = _runtime(served)
     try:
