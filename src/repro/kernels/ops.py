@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import jax.tree_util as jtu
 
-from repro import compat
+from repro import compat, obs
 from repro.kernels import layout as L
 from repro.kernels import ref as R
 from repro.kernels import segment_mm as SK
@@ -38,8 +38,17 @@ Backend = str  # 'xla' | 'pallas' | 'pallas_interpret'
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass(frozen=True, eq=False)
 class PaddedSegmentsDev:
-    row_map: jnp.ndarray      # [Rp]
-    inv_map: jnp.ndarray      # [M]
+    """A tile-aligned padded layout on the device.
+
+    Invariant (``pad_segments``, ``pad_segments_rows`` and
+    ``device_pad_segments`` all build it so): ``inv_map`` is injective,
+    ``row_map[inv_map] == arange(M)``, and every other slot of ``row_map``
+    is -1. ``pad_rows`` and ``unpad_rows`` rely on it to transpose each
+    other exactly.
+    """
+
+    row_map: jnp.ndarray      # [Rp] compact row of each slot, -1 for pad
+    inv_map: jnp.ndarray      # [M] slot of each compact row
     t2g: jnp.ndarray          # [T]
     tile: int
     num_groups: int
@@ -128,14 +137,72 @@ jtu.register_pytree_node(
     _Static, lambda s: ((), s.value), lambda aux, _: _Static(aux))
 
 
-def pad_rows(x: jnp.ndarray, row_map: jnp.ndarray,
-             fill: float = 0.0) -> jnp.ndarray:
-    """Gather rows into the padded layout; pad rows get ``fill``."""
-    valid = (row_map >= 0)
+# ---------------------------------------------------------------------------
+# the tile padding permutation: compact rows <-> tile-padded rows
+#
+# By the invariant of ``PaddedSegmentsDev``, padding is a permutation that
+# adds zero rows and un-padding one that drops them, each the other's exact
+# transpose. The custom VJPs say so, so the backward moves rows with a
+# gather, not with the scatter-add into zeros that XLA derives for a gather.
+# ---------------------------------------------------------------------------
+def _float0(a: jnp.ndarray) -> np.ndarray:
+    return np.zeros(a.shape, dtype=jax.dtypes.float0)
+
+
+@jax.custom_vjp
+def _pad(x, row_map, inv_map):
+    valid = row_map >= 0
     xp = x[jnp.maximum(row_map, 0)]
     if x.ndim == 1:
-        return jnp.where(valid, xp, fill)
-    return jnp.where(valid[:, None], xp, fill)
+        return jnp.where(valid, xp, 0.0)
+    return jnp.where(valid[:, None], xp, 0.0)
+
+
+@jax.custom_vjp
+def _unpad(y_p, row_map, inv_map):
+    return y_p[inv_map]
+
+
+def _pad_fwd(x, row_map, inv_map):
+    return _pad(x, row_map, inv_map), (row_map, inv_map)
+
+
+def _pad_bwd(res, dy_p):
+    row_map, inv_map = res
+    # pad slots held a constant: they carry no gradient and are dropped
+    return _unpad(dy_p, row_map, inv_map), _float0(row_map), _float0(inv_map)
+
+
+def _unpad_fwd(y_p, row_map, inv_map):
+    return _unpad(y_p, row_map, inv_map), (row_map, inv_map)
+
+
+def _unpad_bwd(res, dy):
+    row_map, inv_map = res
+    return _pad(dy, row_map, inv_map), _float0(row_map), _float0(inv_map)
+
+
+_pad.defvjp(_pad_fwd, _pad_bwd)
+_unpad.defvjp(_unpad_fwd, _unpad_bwd)
+
+
+def _count_perm(op: str) -> None:
+    """Runs where the op is traced (on the host, once per trace under jit)."""
+    obs.metrics().counter("padded_perm_traced", op=op).inc()
+
+
+def pad_rows(x: jnp.ndarray, lay: PaddedSegmentsDev) -> jnp.ndarray:
+    """Compact rows [M, ...] -> tile-padded rows [Rp, ...]; pad rows are 0.
+    Its VJP is ``unpad_rows``."""
+    _count_perm("pad")
+    return _pad(x, lay.row_map, lay.inv_map)
+
+
+def unpad_rows(y_p: jnp.ndarray, lay: PaddedSegmentsDev) -> jnp.ndarray:
+    """Tile-padded rows [Rp, ...] -> compact rows [M, ...] (``y_p[inv_map]``).
+    Its VJP is ``pad_rows``."""
+    _count_perm("unpad")
+    return _unpad(y_p, lay.row_map, lay.inv_map)
 
 
 # ---------------------------------------------------------------------------
@@ -238,10 +305,10 @@ def segment_mm(
     if x_sorted.shape[0] == 0:
         # empty block (e.g. a sampled hop with no edges): no tiles to sweep
         return jnp.zeros((0, w.shape[-1]), x_sorted.dtype)
-    x_p = pad_rows(x_sorted, lay.row_map)
+    x_p = pad_rows(x_sorted, lay)
     scale_p = None
     if row_scale is not None:
-        scale_p = pad_rows(row_scale, lay.row_map)[:, None]
+        scale_p = pad_rows(row_scale, lay)[:, None]
     tr = _fit_tile_rows(lay.tile, tile_rows)
     t2g = _subtile_t2g(lay.t2g, lay.tile, tr)
     if backend == "xla":
@@ -254,7 +321,7 @@ def segment_mm(
         if scale_p is None:
             scale_p = jnp.ones((x_p.shape[0], 1), x_p.dtype)
         y_p = f(x_p, w, scale_p, t2g)
-    return y_p[lay.inv_map]
+    return unpad_rows(y_p, lay)
 
 
 def gather_mm(
@@ -351,7 +418,7 @@ def segment_mm_gather(
         return jnp.zeros((0, n), x_src.dtype)
     scale_p = None
     if row_scale is not None:
-        scale_p = pad_rows(row_scale, lay.row_map)[:, None]
+        scale_p = pad_rows(row_scale, lay)[:, None]
     tr = _fit_tile_rows(lay.tile, tile_rows)
     t2g = _subtile_t2g(lay.t2g, lay.tile, tr)
     if backend == "xla":
@@ -367,7 +434,7 @@ def segment_mm_gather(
         if scale_p is None:
             scale_p = jnp.ones((gather_rows.shape[0], 1), x_src.dtype)
         y_p = f(x_src, w, scale_p, gather_rows, t2g)
-    return y_p[lay.inv_map]
+    return unpad_rows(y_p, lay)
 
 
 # ---------------------------------------------------------------------------

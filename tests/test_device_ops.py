@@ -26,10 +26,19 @@ def graph():
                                  num_etypes=7, seed=0)
 
 
+_STEPS = {}
+
+
 def _train_step_hlo(graph, model):
     """One step of a tiny full-graph trainer on the Pallas kernels
-    (interpret mode); returns the step's optimized HLO text and the number
-    of unique (source, relation) pairs, the rows of a compact message."""
+    (interpret mode); returns the step's optimized HLO text and the engine
+    (compiled once per model)."""
+    if model not in _STEPS:
+        _STEPS[model] = _compile_train_step(graph, model)
+    return _STEPS[model]
+
+
+def _compile_train_step(graph, model):
     eng = RGNNEngine(graph, EngineConfig(
         model=model, layers=2, dim=16, hidden=12, classes=6, fanouts=[3, 3],
         backend="pallas_interpret", tile=8, node_block=8, seed=0))
@@ -40,7 +49,7 @@ def _train_step_hlo(graph, model):
                           opt=AdamW(learning_rate=1e-2), log=None)
     tr.step(tr.init_state(eng.init_params(jax.random.key(0))))
     (_, compiled), = tr.step_exec._cache.values()
-    return compiled.as_text(), int(eng.gt.unique_src.shape[0])
+    return compiled.as_text(), eng
 
 
 def _instructions(text):
@@ -59,7 +68,8 @@ def _instructions(text):
 
 @pytest.mark.parametrize("model", ["rgat", "rgcn"])
 def test_train_step_instructions_have_owners(graph, model):
-    text, unique_pairs = _train_step_hlo(graph, model)
+    text, eng = _train_step_hlo(graph, model)
+    unique_pairs = int(eng.gt.unique_src.shape[0])   # compact message rows
     module, table = device_ops.parse_hlo(text)
     assert module == "jit_hector_train_step"
     assert device_ops.table(module) == table      # recorded at compile
@@ -83,6 +93,38 @@ def test_train_step_instructions_have_owners(graph, model):
                  for _, rtype, opcode, o in instrs
                  if opcode == "scatter" and rtype.startswith(want)}
         assert owned == {(True, "backward", "scatter-add")}, (layer, owned)
+
+
+def _rows(rtype):
+    return int(rtype.split("[")[1].split(",")[0].split("]")[0])
+
+
+@pytest.mark.parametrize("model", ["rgat", "rgcn"])
+def test_padding_transposes_are_owned_gemm_gathers(graph, model):
+    """The backward of the GEMM's tile padding (``ops.pad_rows`` and
+    ``ops.unpad_rows``) moves rows with gathers into the padded rows, owned
+    by that GEMM op, backward; no scatter writes padded rows, and no gather
+    or scatter is left without an owner."""
+    text, eng = _train_step_hlo(graph, model)
+    lays = eng.layouts
+    segs = (lays.edge_seg, lays.unique_seg, lays.node_seg)
+    padded = {int(s.row_map.shape[0]) for s in segs}
+    compact = {int(s.inv_map.shape[0]) for s in segs}
+    instrs = [(rtype, opcode, o) for _, rtype, opcode, o in
+              _instructions(text) if opcode in ("gather", "scatter")]
+    assert all(o is not None for _, _, o in instrs)
+    assert not [(rtype, o) for rtype, opcode, o in instrs
+                if opcode == "scatter" and _rows(rtype) in padded]
+    # the GEMM ops that un-pad their rows (a dense GEMM such as RGCN's
+    # ``h_self`` has no padding)
+    unpadding = {o.owner for rtype, opcode, o in instrs
+                 if opcode == "gather" and _rows(rtype) in compact
+                 and o.direction == "forward" and ".gemm." in o.owner}
+    assert unpadding
+    backward = {o.owner for rtype, opcode, o in instrs
+                if opcode == "gather" and _rows(rtype) in padded
+                and o.direction == "backward"}
+    assert backward == unpadding
 
 
 def test_scope_names_never_match_a_kernel_name(graph):

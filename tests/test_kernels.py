@@ -1,5 +1,8 @@
 """Per-kernel shape/dtype sweeps + gradient checks vs the ref.py oracles
 (deliverable c: each Pallas kernel validated in interpret mode)."""
+import collections
+import re
+
 import numpy as np
 import pytest
 import jax
@@ -303,3 +306,170 @@ def test_property_segment_mm_matches_ref(n_groups, k, n, seed):
     y = ops.segment_mm(x, w, lay, backend="pallas_interpret")
     np.testing.assert_allclose(
         y, R.segment_mm_ref(x, w, jnp.asarray(seg_ids)), rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the tile padding permutation (``ops.pad_rows`` / ``ops.unpad_rows``)
+# ---------------------------------------------------------------------------
+_SIZES = np.array([5, 0, 13, 8, 0, 1, 17, 0])   # empty groups, first to last
+
+
+def _padding(sizes=_SIZES, tile=8, grow_tiles=0):
+    ptr = np.zeros(len(sizes) + 1, np.int64)
+    np.cumsum(sizes, out=ptr[1:])
+    ps = L.pad_segments(ptr, tile)
+    if grow_tiles:
+        ps = L.pad_segments_rows(ps, ps.padded_rows + grow_tiles * tile)
+    return ps
+
+
+def _plain_padding(mp):
+    """Install the padding pair as plain gathers, whose transposes XLA
+    derives as scatter-adds into zeros (the formulation the custom VJPs
+    replace)."""
+    def pad_rows(x, lay):
+        valid = lay.row_map >= 0
+        xp = x[jnp.maximum(lay.row_map, 0)]
+        return jnp.where(valid if x.ndim == 1 else valid[:, None], xp, 0.0)
+
+    mp.setattr(ops, "pad_rows", pad_rows)
+    mp.setattr(ops, "unpad_rows", lambda y_p, lay: y_p[lay.inv_map])
+
+
+def _gemm_case(rng, op, ps, with_scale, k=12, n=10, n_src=30):
+    """``(loss(x, w, s, lay, backend), x, w, s, lay)`` of ``op`` over the
+    layout ``ps``."""
+    lay = ops.padded_segments_dev(ps)
+    m = int(ps.seg_sizes.sum())
+    idx = rng.integers(0, n_src, m).astype(np.int32)
+    feats = jnp.asarray(rng.normal(size=(n_src, k)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(ps.num_groups, k, n)), jnp.float32)
+    s = jnp.asarray(rng.normal(size=(m,)), jnp.float32)
+    c = jnp.asarray(rng.normal(size=(m, n)), jnp.float32)
+    gmap = jnp.asarray(L.compose_gather_rows(ps, idx))
+
+    def loss(x, w, s, lay, backend):
+        scale = s if with_scale else None
+        if op == "segment_mm":
+            y = ops.segment_mm(x, w, lay, row_scale=scale, backend=backend)
+        else:
+            y = ops.segment_mm_gather(x, w, lay, gmap, row_scale=scale,
+                                      backend=backend)
+        return jnp.sum(y * c)
+
+    x = feats[idx] if op == "segment_mm" else feats
+    return loss, x, w, s, lay
+
+
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.uint32)
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("op", ["segment_mm", "segment_mm_gather"])
+@pytest.mark.parametrize("with_scale", [False, True])
+@pytest.mark.parametrize("grow_tiles", [0, 3])
+def test_padding_transpose_grads_bitwise(rng, monkeypatch, backend, op,
+                                         with_scale, grow_tiles):
+    """Gradients through the custom-VJP padding pair are bitwise equal to
+    those through the scatter-add transpose XLA derives for the plain
+    gathers, with empty groups and with a layout grown by
+    ``pad_segments_rows``."""
+    loss, x, w, s, lay = _gemm_case(rng, op, _padding(grow_tiles=grow_tiles),
+                                    with_scale)
+
+    def grads():
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2)),
+                       static_argnums=4)(x, w, s, lay, backend)
+
+    with monkeypatch.context() as mp:
+        _plain_padding(mp)
+        want = grads()
+    got = grads()
+    for name, a, b in zip("xws", got, want):
+        np.testing.assert_array_equal(_bits(a), _bits(b), err_msg=name)
+
+
+_INSTR = re.compile(r"=\s*(\w+)\[([\d,]*)\]\S*\s+([a-z][\w\-]*)\(")
+
+
+def _compiled_ops(fn, *args):
+    """(opcode, result shape) of every instruction of ``fn``'s optimized
+    HLO on the CPU."""
+    text = jax.jit(lambda *a: fn(*a), static_argnums=len(args) - 1) \
+        .lower(*args).compile().as_text()     # a fresh trace every call
+    return [(m.group(3), tuple(int(d) for d in m.group(2).split(",") if d))
+            for m in _INSTR.finditer(text)]
+
+
+@pytest.mark.parametrize("op", ["segment_mm", "segment_mm_gather"])
+def test_padding_transpose_hlo(rng, monkeypatch, op):
+    """The XLA backend's compiled grad holds no scatter into Rp or M rows,
+    and the forward compiles to the opcodes of the plain gathers."""
+    ps = _padding()
+    rows = {ps.padded_rows, int(ps.seg_sizes.sum())}
+    loss, x, w, s, lay = _gemm_case(rng, op, ps, with_scale=True)
+    # dW's scatter (into R rows) and the source gather's (into the 30
+    # source rows of ``segment_mm_gather``) stay; neither may alias
+    assert not rows & {30, ps.num_groups}
+
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+
+    def padded_scatters(ops_):
+        return [shape for opcode, shape in ops_
+                if opcode == "scatter" and shape and shape[0] in rows]
+
+    with monkeypatch.context() as mp:
+        _plain_padding(mp)
+        plain_grad = _compiled_ops(grad, x, w, s, lay, "xla")
+        plain_fwd = _compiled_ops(loss, x, w, s, lay, "xla")
+    assert padded_scatters(plain_grad)        # the detector sees them
+    assert not padded_scatters(_compiled_ops(grad, x, w, s, lay, "xla"))
+    assert collections.Counter(o for o, _ in _compiled_ops(
+        loss, x, w, s, lay, "xla")) == collections.Counter(
+            o for o, _ in plain_fwd)
+
+
+@pytest.mark.parametrize("builder", ["pad_segments", "pad_segments_rows",
+                                     "device_pad_segments"])
+@pytest.mark.parametrize("sizes", [_SIZES, np.array([0]), np.array([3, 9])])
+def test_padding_map_invariant(builder, sizes):
+    """``inv_map`` is injective, ``row_map[inv_map] == arange(M)``, and
+    every other slot of ``row_map`` is -1: what the padding pair's VJPs
+    rest on."""
+    tile, m = 4, int(sizes.sum())
+    if builder == "device_pad_segments":
+        ptr = np.zeros(len(sizes) + 1, np.int32)
+        np.cumsum(sizes, out=ptr[1:])
+        rp = tile * (-(-m // tile) + len(sizes) + 2)   # room for any padding
+        row_map, inv_map, _ = L.device_pad_segments(
+            jnp.asarray(ptr), jnp.asarray(np.repeat(np.arange(len(sizes)),
+                                                    sizes).astype(np.int32)),
+            tile, rp)
+        row_map, inv_map = np.asarray(row_map), np.asarray(inv_map)
+    else:
+        grow = 2 if builder == "pad_segments_rows" else 0
+        ps = _padding(sizes, tile, grow_tiles=grow)
+        row_map, inv_map = ps.row_map, ps.inv_map
+    assert inv_map.shape == (m,)
+    assert len(np.unique(inv_map)) == m
+    np.testing.assert_array_equal(row_map[inv_map], np.arange(m))
+    others = np.ones(row_map.shape[0], bool)
+    others[inv_map] = False
+    assert np.all(row_map[others] == -1)
+
+
+def test_padded_perm_traced_counter(rng):
+    """One count per padding or un-padding traced, none per cached call."""
+    from repro import obs
+    ps = _padding()
+    _, x, w, s, lay = _gemm_case(rng, "segment_mm", ps, with_scale=True)
+    f = jax.jit(lambda x, w, s, lay: ops.segment_mm(x, w, lay, row_scale=s))
+    with obs.scope() as st:
+        f(x, w, s, lay)
+        f(x, w, s, lay)
+        reg = st.registry
+        assert reg.counter("padded_perm_traced", op="pad").value == 2
+        assert reg.counter("padded_perm_traced", op="unpad").value == 1
+        jax.jit(lambda x, lay: ops.segment_mm(x, w, lay))(x, lay)
+        assert reg.counter_total("padded_perm_traced") == 5
