@@ -9,15 +9,29 @@ Everything is found by name from ``BENCHMARK.json``:
 * the traffic mix is ``bench/traffic/<traffic>.json``: parameters, and the
   ``runner`` (``bench/runners/<runner>.py``) that runs such a mix;
 * the cell's correctness limits are ``bench/limits/<cell>.json``;
-* each per-layer metric is read by ``bench/metrics/<metric>.py``.
+* each per-layer metric is read by ``bench/metrics/<metric>.py``;
+* the configuration's ``reference`` key names the model's plain reference,
+  ``bench/reference/<reference>.py``, and its work counts,
+  ``bench/counts/<reference>.py``.
 
 A new configuration, mix, cell or metric is new files and entries; no
-existing file changes.
+existing file changes. A new model brings exactly these files:
+
+* ``bench/reference/<reference>.py``: ``param_shapes(dims, num_etypes,
+  num_ntypes)`` and ``layer(p, x, dg, num_nodes, chunk, precision)``
+  (``bench/reference/stack.py``);
+* ``bench/counts/<reference>.py``: ``step(stats, dims, graph, train)``
+  (``bench/work.py``);
+* ``bench/configs/<config>.json`` and ``bench/limits/<cell>.json``;
+
+and entries in ``BENCHMARK.json``: the configuration, the cell, and the
+cell's name in the ``workloads`` of each metric it reports.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import importlib
 import importlib.util
 import json
@@ -144,14 +158,25 @@ def runner(cell: Cell):
     return importlib.import_module(f"bench.runners.{cell.traffic['runner']}")
 
 
-def metric_reader(name: str, root: pathlib.Path = ROOT) -> Callable:
-    """``bench/metrics/<name>.py``'s ``read(data) -> float | None``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
+@functools.lru_cache(maxsize=None)
+def module(kind: str, name: str, root: pathlib.Path = ROOT):
+    """``bench/<kind>/<name>.py`` of the checkout at ``root``, loaded once;
+    a missing file is an error that names it."""
+    path = root / "bench" / kind / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"bench: {name!r} needs the file bench/{kind}/{name}.py, which "
+            f"{root} does not have")
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
+        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, root: pathlib.Path = ROOT) -> Callable:
+    """``bench/metrics/<name>.py``'s ``read(data) -> float | None``."""
+    return module("metrics", name, root).read
 
 
 # ---------------------------------------------------------------------------

@@ -20,7 +20,7 @@ lo*hi for "high", hi*hi for "bfloat16".
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict
+from typing import Dict, Optional
 
 import numpy as np
 import jax
@@ -61,13 +61,15 @@ class EdgeGraph:
     ``src[r, m]`` / ``dst[r, m]`` are the ends of relation ``r``'s ``m``-th
     edge. Pad slots have ``dst == num_nodes``, a dummy segment that no real
     node reads; relations are padded with empty blocks to a multiple of
-    ``chunk``, the number of relations a reference computes at once."""
+    ``chunk``, the number of relations a reference computes at once.
+    ``node_type[v]`` is node ``v``'s type, where the graph has them."""
 
     src: np.ndarray          # [Rp, M] int32
     dst: np.ndarray          # [Rp, M] int32
     num_nodes: int
     num_etypes: int
     chunk: int
+    node_type: Optional[np.ndarray] = None    # [num_nodes] int32
 
 
 # largest [chunk, M, width] float32 intermediate a reference makes at once
@@ -75,9 +77,11 @@ CHUNK_BYTES = 1 << 29
 
 
 def edge_graph(src, dst, etype, num_nodes: int, num_etypes: int,
-               block: int = 1, width: int = 64) -> EdgeGraph:
+               block: int = 1, width: int = 64,
+               node_type=None) -> EdgeGraph:
     """Group the edges by relation (block length rounded up to a multiple
-    of ``block``); ``width`` sizes the relation chunks."""
+    of ``block``); ``width`` sizes the relation chunks; ``node_type`` is
+    kept as it is."""
     src = np.asarray(src, np.int32)
     dst = np.asarray(dst, np.int32)
     etype = np.asarray(etype, np.int32)
@@ -93,11 +97,19 @@ def edge_graph(src, dst, etype, num_nodes: int, num_etypes: int,
     d = np.full((rp, m), num_nodes, np.int32)
     s[etype[order], slot] = src[order]
     d[etype[order], slot] = dst[order]
-    return EdgeGraph(s, d, int(num_nodes), int(num_etypes), chunk)
+    if node_type is not None:
+        node_type = np.asarray(node_type, np.int32)
+    return EdgeGraph(s, d, int(num_nodes), int(num_etypes), chunk,
+                     node_type)
 
 
 def device_graph(g: EdgeGraph) -> Dict[str, jnp.ndarray]:
-    return {"src": jnp.asarray(g.src), "dst": jnp.asarray(g.dst)}
+    """What a model's ``layer`` reads: ``src`` and ``dst`` blocks, and
+    ``node_type`` where the graph has them."""
+    dg = {"src": jnp.asarray(g.src), "dst": jnp.asarray(g.dst)}
+    if g.node_type is not None:
+        dg["node_type"] = jnp.asarray(g.node_type)
+    return dg
 
 
 def pad_relations(w, rp: int):

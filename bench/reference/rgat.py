@@ -19,8 +19,9 @@ from bench.reference import common as C
 SLOPE = 0.01
 
 
-def param_shapes(dims, num_etypes: int):
-    """The parameter pytree the model takes, one dict per layer."""
+def param_shapes(dims, num_etypes: int, num_ntypes: int):
+    """The parameter pytree the model takes, one dict per layer (RGAT has
+    no node-typed weight)."""
     return [{"W_rel": (num_etypes, k, n), "w_att_src": (num_etypes, n),
              "w_att_dst": (num_etypes, n)}
             for k, n in zip(dims[:-1], dims[1:])]

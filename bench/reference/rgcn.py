@@ -16,7 +16,8 @@ import jax.numpy as jnp
 from bench.reference import common as C
 
 
-def param_shapes(dims, num_etypes: int):
+def param_shapes(dims, num_etypes: int, num_ntypes: int):
+    """One dict per layer (RGCN has no node-typed weight)."""
     return [{"W_rel": (num_etypes, k, n), "W_self": (k, n)}
             for k, n in zip(dims[:-1], dims[1:])]
 

@@ -1,26 +1,40 @@
 """Model-agnostic reference runners: the layer stack, the full-graph loss
 and AdamW steps, and the sampled-block forward.
 
-A model module (``rgat``, ``rgcn``) provides ``param_shapes(dims, R)`` and
-``layer(p, x, dg, num_nodes, chunk, precision)`` over the relation blocks
-of ``common.EdgeGraph``; layers are joined by relu.
+A model's plain reference is one file, ``bench/reference/<reference>.py``
+(``rgat``, ``rgcn``; named by the configuration's ``reference`` key),
+which provides
+
+* ``param_shapes(dims, num_etypes, num_ntypes)``: the parameter pytree,
+  one dict per layer of name -> shape; a weight indexed by relation leads
+  with ``num_etypes``, one indexed by node type with ``num_ntypes``;
+* ``layer(p, x, dg, num_nodes, chunk, precision)``: one layer over the
+  relation blocks of ``common.EdgeGraph``. ``dg`` is
+  ``common.device_graph``'s dict: ``src`` and ``dst`` blocks, and
+  ``node_type`` [num_nodes] in full-graph training. A layer reads the
+  number of node types from its weights' leading axis.
+
+Layers are joined by relu. Its work counts are ``bench/counts/`` files
+(``bench/work.py``).
 """
 from __future__ import annotations
 
 import functools
-import importlib
+import pathlib
 from typing import Dict, List, Sequence
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
+from bench import harness
 from bench.reference import common as C
 
 
-def model(name: str):
-    """The reference module for a configuration's ``reference`` key."""
-    return importlib.import_module(f"bench.reference.{name}")
+def model(name: str, root: pathlib.Path = harness.ROOT):
+    """The reference module for a configuration's ``reference`` key, from
+    the checkout at ``root``."""
+    return harness.module("reference", name, root)
 
 
 def forward(mod, params, x, dg, num_nodes: int, chunk: int,

@@ -75,10 +75,11 @@ def make_inputs(cell: Cell, num_nodes: int):
     """Weights, features and labels for the run's seed, made on the device
     in one jitted call: the same seed gives the same arrays."""
     cfg = cell.config
-    mod = stack.model(cfg["reference"])
+    g = cfg["graph"]
+    mod = stack.model(cfg["reference"], cell.root)
     shapes = tuple(tuple(sorted((k, tuple(v)) for k, v in layer.items()))
                    for layer in mod.param_shapes(
-                       dims(cfg), cfg["graph"]["num_etypes"]))
+                       dims(cfg), g["num_etypes"], g["num_ntypes"]))
     key = jax.random.key(seed_int(cell.seed, TAG_INPUTS))
     return _input_fn(shapes, num_nodes, cfg["model"]["dim"],
                      cfg["model"]["classes"])(key)

@@ -307,7 +307,7 @@ def reference_logits(cell: Cell, seq, params, table, sizes, precision: str
                      ) -> np.ndarray:
     """The reference's logits for every seed of a batch, in seed order."""
     x0 = np.asarray(table[jax.numpy.asarray(seq.blocks[0].node_ids)])
-    out = stack.forward_blocks(stack.model(cell.config["reference"]),
+    out = stack.forward_blocks(stack.model(cell.config["reference"], cell.root),
                                params, x0, block_hops(seq),
                                cell.config["graph"]["num_etypes"], sizes,
                                precision)

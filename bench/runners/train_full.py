@@ -136,10 +136,11 @@ def reference(cell: Cell, arrays, steps: int, precision: str = "highest",
     n = int(arrays["node_type"].size)
     params, feats, labels = common.make_inputs(cell, n)
     g = RC.edge_graph(arrays["src"], arrays["dst"], arrays["etype"], n,
-                      cfg["graph"]["num_etypes"])
+                      cfg["graph"]["num_etypes"],
+                      node_type=arrays["node_type"])
     rows = common.loss_rows(cell, n) if rows is None else rows
-    return stack.train_steps(stack.model(cfg["reference"]), params, feats,
-                             labels, rows, g,
+    return stack.train_steps(stack.model(cfg["reference"], cell.root),
+                             params, feats, labels, rows, g,
                              RC.AdamWConfig.from_config(cfg["optimizer"]),
                              steps, precision)
 
@@ -147,6 +148,14 @@ def reference(cell: Cell, arrays, steps: int, precision: str = "highest",
 def run(cell: Cell, devices, counter) -> Outcome:
     steps = int(cell.traffic["first_steps"])
     arrays = common.load_arrays(cell)
+    layer = {}
+    if cell.trace:      # before set-up, so a model with no counts fails fast
+        stats = work.graph_stats(arrays["src"], arrays["dst"],
+                                 arrays["etype"],
+                                 int(arrays["node_type"].size))
+        layer.update(work=work.step_work(cell.config, stats, True,
+                                         cell.root),
+                     device_kind=devices[0].device_kind)
     prog = Program(cell, arrays)
     first = prog.first_steps(steps)
     setup_s = time.perf_counter() - cell.t_start
@@ -190,15 +199,7 @@ def run(cell: Cell, devices, counter) -> Outcome:
         f"{ref['losses']}; readings {readings}")
     checks = [Check(k, readings[k], cell.limit(k)) for k in CHECKED]
 
-    layer = {"window_s": t1 - t0, "steps": n}
-    if cell.trace:
-        cfg = cell.config
-        stats = work.graph_stats(arrays["src"], arrays["dst"],
-                                 arrays["etype"],
-                                 int(arrays["node_type"].size))
-        layer["work"] = work.STEP_WORK[cfg["reference"]](
-            stats, common.dims(cfg), cfg["graph"]["num_etypes"], True)
-        layer["device_kind"] = devices[0].device_kind
+    layer.update(window_s=t1 - t0, steps=n)
     return Outcome(attempted=n, failed=failed,
                    metrics={"full_step_ms": step_ms, "setup_s": setup_s},
                    checks=checks, memory_peak_bytes=peak, layer=layer,

@@ -1,6 +1,7 @@
 """The harness is driven by data: a configuration, a traffic mix, a cell
 and a per-layer metric are new files and entries, with no edit to an
-existing file, and ``bench/run.py`` finds each by name."""
+existing file, and ``bench/run.py`` finds each by name. So does a new
+model with node-typed weights: its reference and counts are new files."""
 import json
 import os
 import pathlib
@@ -9,9 +10,14 @@ import subprocess
 import sys
 
 import jax
+import numpy as np
+import pytest
 
 import bench_tiny as tiny
-from bench import harness
+from bench import harness, work
+from bench.runners import common
+
+DATA = pathlib.Path(__file__).with_name("data")
 
 NEW_METRIC = '''
 def read(data):
@@ -21,7 +27,9 @@ def read(data):
 
 def _checkout(tmp_path: pathlib.Path) -> pathlib.Path:
     """A copy of the benchmark's own files (what a checkout of ``paths``
-    holds) with a new configuration, mix, cell and metric dropped in."""
+    holds) with a new configuration, mix, cell and metric dropped in, and
+    a new model, single-head HGT, whose K/Q/V weights are indexed by node
+    type: its reference, counts, configuration, limits and cell."""
     root = tmp_path / "checkout"
     shutil.copytree(harness.ROOT / "bench", root / "bench",
                     ignore=shutil.ignore_patterns(".cache", "__pycache__"))
@@ -53,6 +61,24 @@ def _checkout(tmp_path: pathlib.Path) -> pathlib.Path:
                                "layer": "serve runtime",
                                "moves": "request_p95_ms",
                                "workloads": ["rgcn-am.serve_slow"]})
+
+    hgt = json.loads((root / "bench/configs/rgat-am.json").read_text())
+    hgt["name"] = "hgt-am"
+    hgt["model"]["name"] = hgt["reference"] = "hgt"
+    (root / "bench/configs/hgt-am.json").write_text(json.dumps(hgt))
+    shutil.copy(DATA / "hgt_reference.py", root / "bench/reference/hgt.py")
+    shutil.copy(DATA / "hgt_counts.py", root / "bench/counts/hgt.py")
+    shutil.copy(root / "bench/limits/rgat-am.train_full.json",
+                root / "bench/limits/hgt-am.train_full.json")
+    bench["configs"].append({"name": "hgt-am", "source": "test",
+                             "file": "bench/configs/hgt-am.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "hgt-am.train_full",
+                               "config": "hgt-am", "traffic": "train_full",
+                               "chips": 1, "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "rgat-am.train_full" in m.get("workloads", ()):
+            m["workloads"].append("hgt-am.train_full")
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     after = {p.relative_to(root): p.read_bytes()
              for p in (root / "bench").rglob("*") if p.is_file()}
@@ -83,6 +109,53 @@ def test_new_files_are_found_by_name(tmp_path):
     bench = harness.load_benchmark(root)
     layer = harness.per_layer(bench, c, dict(out.layer, trace=None))
     assert layer["served.serve_slow"]["value"] > 0
+
+
+def _hgt_cell(tmp_path):
+    return tiny.cell("hgt-am.train_full", tmp_path,
+                     root=_checkout(tmp_path))
+
+
+def test_node_typed_model_joins_by_new_files(tmp_path):
+    """HGT's weights W_K, W_Q, W_V lead with the node-type count; its
+    reference reads node types, and its cell runs correct through the
+    same runner, with its counts found by name."""
+    c = _hgt_cell(tmp_path)
+    out = tiny.run(c)
+    assert tiny.correct(out), out.checks
+    arrays = common.load_arrays(c)
+    params, _, _ = common.make_inputs(c, int(arrays["node_type"].size))
+    t = c.config["graph"]["num_ntypes"]
+    assert params[0]["W_K"].shape == (t, 64, 64)
+    stats = work.graph_stats(arrays["src"], arrays["dst"], arrays["etype"],
+                             int(arrays["node_type"].size))
+    w = work.step_work(c.config, stats, True, c.root)
+    assert w["model_flops"] > 0 and all(
+        w[f]["flops"] > 0 and w[f]["bytes"] > 0
+        for f in ("segment_mm", "traversal"))
+
+
+def test_node_types_moved_are_not_correct(tmp_path, monkeypatch):
+    """The program given other node types than the reference reads: each
+    node takes the next type (the last type keeps its own, since the
+    program needs nodes sorted by type). The comparison must see it."""
+    from repro.core.graph import HeteroGraph
+    orig = HeteroGraph.from_edges
+
+    def from_edges(*args, node_type=None, num_ntypes=1, **kw):
+        moved = np.minimum(np.asarray(node_type) + 1, num_ntypes - 1)
+        return orig(*args, node_type=moved, num_ntypes=num_ntypes, **kw)
+    monkeypatch.setattr(HeteroGraph, "from_edges", staticmethod(from_edges))
+    out = tiny.run(_hgt_cell(tmp_path))
+    assert not tiny.correct(out), out.checks
+
+
+def test_traced_run_without_counts_names_the_file(tmp_path):
+    c = _hgt_cell(tmp_path)
+    (c.root / "bench/counts/hgt.py").unlink()
+    c.trace = True
+    with pytest.raises(FileNotFoundError, match="bench/counts/hgt.py"):
+        tiny.run(c)
 
 
 def test_run_without_a_tpu_prints_no_result(tmp_path):
