@@ -1,0 +1,10 @@
+"""Node-typed GEMMs per full-graph training step (ms): device time of the
+step's instructions owned by a GEMM over nodes with node-typed weights
+(scope kind ``nodegemm``: HGT's K, Q, V and A-Linear), forward and
+backward (``bench/device_owners.py``)."""
+from bench import device_owners as D
+
+
+def read(data):
+    return D.ms_per_step(data, lambda o: o is not None
+                         and o.owner.split(".")[1:2] == ["nodegemm"])

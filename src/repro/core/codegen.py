@@ -132,7 +132,9 @@ def init_params(
         else:
             lead = ()
         shape = lead + tuple(w.shape)
-        fan_in = w.shape[0] if len(w.shape) >= 1 else 1
+        # a per-head matrix (H, k, n) takes k; a matrix or vector its first
+        fan_in = w.shape[-2] if len(w.shape) >= 3 else (
+            w.shape[0] if len(w.shape) >= 1 else 1)
         scale = 1.0 / math.sqrt(max(1, fan_in))
         params[name] = (jax.random.normal(k, shape) * scale).astype(dtype)
     return params
@@ -190,6 +192,8 @@ def _elementwise(op: str, args, alpha: float = 0.01):
             return jax.nn.sigmoid(a)
         if op == "tanh":
             return jnp.tanh(a)
+        if op == "gelu":
+            return jax.nn.gelu(a, approximate=False)
         if op == "neg":
             return -a
         raise ValueError(op)
@@ -217,11 +221,20 @@ def op_scope(op, layer: int = 0) -> str:
     """The ``jax.named_scope`` an op spec's device work carries:
     ``l<layer>.<kind>.<output>`` (``l1.traversal.h_out``). Compiled
     instructions keep it in their ``op_name`` metadata, under
-    ``transpose(...)`` in the backward pass (``obs/device_ops.py``)."""
+    ``transpose(...)`` in the backward pass (``obs/device_ops.py``).
+
+    Kinds: ``gemm`` (edgewise or untyped GEMMs), ``nodegemm`` (GEMMs over
+    nodes with node-typed weights), ``traversal`` (edge regions: scores,
+    edge softmax, aggregation), ``nodewise`` (elementwise over nodes),
+    ``wprod``, ``fallback``."""
     if isinstance(op, O.TraversalSpec):
-        kind, out = "traversal", op.stmts[-1].out
+        kind = ("nodewise" if op.domain == O.LoopDomain.NODES
+                else "traversal")
+        out = op.stmts[-1].out
     elif isinstance(op, O.GemmSpec):
-        kind, out = "gemm", op.out
+        kind = ("nodegemm" if op.type_index == O.TypeIndex.NTYPE
+                else "gemm")
+        out = op.out
     elif isinstance(op, O.WeightProductSpec):
         kind, out = "wprod", op.out
     else:
@@ -349,14 +362,16 @@ _FUSABLE_GATHERS = (O.GatherScheme.BY_EDGE_SRC, O.GatherScheme.BY_EDGE_DST,
                     O.GatherScheme.BY_UNIQUE_SRC)
 
 
-def _gather_fits(src, index_map=None, tile: int = 1) -> bool:
+def _gather_fits(src, index_map=None, tile: int = 1, heads: int = 1) -> bool:
     """Gather-fusion gate: the ungathered source block must fit the VMEM
     budget and the scalar-prefetched slot map (+ per-tile table) SMEM,
     each against the capacity of the device that runs the kernel
-    (``tune/device.py``)."""
+    (``tune/device.py``). With ``heads`` the kernel sees ``heads`` times the
+    rows and slots at ``1/heads`` of the width (``ops._per_head``)."""
     slots = 0 if index_map is None else int(index_map.size)
-    return tunedev.fused_gather_fits(src.shape[0], src.shape[-1],
-                                     src.dtype.itemsize, slots, tile)
+    return tunedev.fused_gather_fits(
+        heads * src.shape[0], src.shape[-1] // heads, src.dtype.itemsize,
+        heads * slots, tile)
 
 
 def _fuse(dec, fits: bool) -> bool:
@@ -386,6 +401,9 @@ def _trav_decision(decisions, kind, msg, compact_msg, kl):
 def _exec_gemm(op: O.GemmSpec, env: _Env, weight, gt: GraphTensors,
                kl: KernelLayouts, backend: str, decisions=None):
     w = weight(op.weight)
+    per_head = op.heads > 1
+    if w.ndim == 4 and not per_head:
+        w = w[:, 0]          # one head: a plain typed linear
 
     scale = None
     if op.per_row_scale is not None:
@@ -417,8 +435,10 @@ def _exec_gemm(op: O.GemmSpec, env: _Env, weight, gt: GraphTensors,
         gmap = gidx = None
 
     typed = op.type_index != O.TypeIndex.NONE
+    # per-head GEMMs take the static defaults: tuned variants are keyed by
+    # one-head shapes
     dec = _gemm_decision(decisions, op, lay, x_src, w, scale is not None) \
-        if typed else None
+        if typed and not per_head else None
     backend_eff = backend
     tile_rows = tile_n = None
     if dec is not None:
@@ -430,7 +450,7 @@ def _exec_gemm(op: O.GemmSpec, env: _Env, weight, gt: GraphTensors,
     # the kernel via the padded gather-index layout — the [rows, k] input
     # copy is never materialized outside the kernel (paper §3.3).
     if (backend_eff != "xla" and typed and gmap is not None
-            and op.gather in _FUSABLE_GATHERS):
+            and op.gather in _FUSABLE_GATHERS and not per_head):
         if _fuse(dec, _gather_fits(x_src, gmap, tile_rows or lay.tile)):
             y = K.segment_mm_gather(x_src, w, lay, gmap, row_scale=scale,
                                     backend=backend_eff,
@@ -464,6 +484,12 @@ def _edge_msg(env: _Env, gt: GraphTensors, kl: KernelLayouts, name: str):
     return v, None, kl.blocked.edge_map
 
 
+def _edge_scalars(v: jnp.ndarray) -> jnp.ndarray:
+    """An edge score or scale as the traversal ops take it: ``[E]`` for one
+    head (a ``[E, 1]`` column squeezed), ``[E, H]`` for H heads."""
+    return v[:, 0] if v.ndim == 2 and v.shape[1] == 1 else v
+
+
 def _exec_traversal(op: O.TraversalSpec, env: _Env, gt: GraphTensors,
                     kl: KernelLayouts, backend: str, decisions=None):
     """Execute a fused traversal region, fusing the canonical softmax(+agg)
@@ -478,9 +504,8 @@ def _exec_traversal(op: O.TraversalSpec, env: _Env, gt: GraphTensors,
         ):
             score_name = stmts[i].ins[0]
             att_name = stmts[i + 6].out
-            scores = env.get_edge_vanilla(score_name)
-            if scores.ndim == 2:
-                scores = scores[:, 0]
+            scores = _edge_scalars(env.get_edge_vanilla(score_name))
+            heads = 1 if scores.ndim == 1 else scores.shape[1]
             nxt = stmts[i + 7] if i + 7 < len(stmts) else None
             if (
                 nxt is not None
@@ -489,14 +514,15 @@ def _exec_traversal(op: O.TraversalSpec, env: _Env, gt: GraphTensors,
             ):
                 msg, msg_rows, slot_map = _edge_msg(env, gt, kl, nxt.ins[0])
                 dec = _trav_decision(decisions, "softmax_agg", msg,
-                                     msg_rows is not None, kl)
+                                     msg_rows is not None, kl) \
+                    if heads == 1 else None
                 backend_eff = backend
                 if dec is not None and dec.backend != tspace.DEFAULT:
                     backend_eff = dec.backend
                 if backend_eff != "xla":
                     # fully fused softmax+aggregate traversal kernel
                     fuse = _fuse(dec, _gather_fits(
-                        msg, slot_map, kl.blocked.edge_tile))
+                        msg, slot_map, kl.blocked.edge_tile, heads))
                     out = K.edge_softmax_agg(
                         scores, msg, gt.dst, gt.num_nodes,
                         bc=kl.blocked, backend=backend_eff,
@@ -520,7 +546,11 @@ def _exec_traversal(op: O.TraversalSpec, env: _Env, gt: GraphTensors,
         elif s.kind == "rowdot":
             a = env.get_edge_vanilla(s.ins[0])
             b = env.get_edge_vanilla(s.ins[1])
-            env.set(s.out, jnp.sum(a * b, axis=-1))
+            if s.heads == 1:
+                env.set(s.out, jnp.sum(a * b, axis=-1))
+            else:   # one dot per head over its block of columns
+                env.set(s.out, jnp.sum(
+                    (a * b).reshape(a.shape[0], s.heads, -1), axis=-1))
         elif s.kind == "concat":
             env.set(s.out, jnp.concatenate(
                 [env.get_edge_vanilla(a) for a in s.ins], axis=-1))
@@ -534,24 +564,26 @@ def _exec_traversal(op: O.TraversalSpec, env: _Env, gt: GraphTensors,
             env.set(s.out, env.get(s.ins[0])[gt.edge_to_unique])
         elif s.kind == "gather_etype_weight":
             env.set(s.out, env.params[s.ins[0]][gt.etype])
+        elif s.kind == "gather_ntype_weight":
+            env.set(s.out, env.params[s.ins[0]][gt.node_type])
         elif s.kind == "segment_max":
             x = env.get_edge_vanilla(s.ins[0])
             mx = compat.segment_max(x, gt.dst, gt.num_nodes)
             env.set(s.out, jnp.where(jnp.isfinite(mx), mx, 0.0))
         elif s.kind == "segment_sum":
             msg, msg_rows, slot_map = _edge_msg(env, gt, kl, s.ins[0])
+            scale = None
+            if s.scale is not None:
+                scale = _edge_scalars(env.get_edge_vanilla(s.scale))
+            heads = 1 if scale is None or scale.ndim == 1 else scale.shape[1]
             dec = _trav_decision(decisions, "weighted_agg", msg,
-                                 msg_rows is not None, kl)
+                                 msg_rows is not None, kl) \
+                if heads == 1 else None
             backend_eff = backend
             if dec is not None and dec.backend != tspace.DEFAULT:
                 backend_eff = dec.backend
             fuse = _fuse(dec, _gather_fits(msg, slot_map,
-                                         kl.blocked.edge_tile))
-            scale = None
-            if s.scale is not None:
-                scale = env.get_edge_vanilla(s.scale)
-                if scale.ndim == 2:
-                    scale = scale[:, 0]
+                                         kl.blocked.edge_tile, heads))
             out = K.weighted_agg(scale, msg, gt.dst, gt.num_nodes,
                                  bc=kl.blocked, backend=backend_eff,
                                  msg_rows=msg_rows, msg_slot_map=slot_map,

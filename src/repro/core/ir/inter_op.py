@@ -93,7 +93,9 @@ class Weight(Expr):
     """Model weight, optionally indexed by a type dimension.
 
     ``indexed_by`` in {None, "etype", "ntype_src", "ntype_dst"}; shape is the
-    *per-type* shape (e.g. (d_in, d_out) for a typed linear).
+    *per-type* shape (e.g. (d_in, d_out) for a typed linear; (H, k, n) for
+    a per-head linear, whose head ``h`` maps the ``h``-th ``k``-wide block
+    of a row to the ``h``-th ``n``-wide block; () for one scalar per type).
     """
     name: str
     shape: Tuple[int, ...]
@@ -122,9 +124,11 @@ class Linear(Expr):
 
 @dataclasses.dataclass(frozen=True)
 class DotProduct(Expr):
-    """Edgewise dot product -> scalar per edge (GEMM-ineligible, §3.3.1)."""
+    """Edgewise dot product -> scalar per edge (GEMM-ineligible, §3.3.1);
+    with ``heads`` > 1 one dot per head's block of columns -> [E, heads]."""
     a: Expr
     b: Expr
+    heads: int = 1
 
     def children(self):
         return (self.a, self.b)
@@ -142,7 +146,7 @@ class Binary(Expr):
 
 @dataclasses.dataclass(frozen=True)
 class Unary(Expr):
-    op: str  # exp | leaky_relu | relu | sigmoid | neg | tanh
+    op: str  # exp | leaky_relu | relu | sigmoid | neg | tanh | gelu
     a: Expr
     alpha: float = 0.01  # leaky_relu slope
 
@@ -235,7 +239,8 @@ def render_expr(e: Expr) -> str:
     if isinstance(e, (TypedLinear, Linear)):
         return f"({render_expr(e.x)} @ {render_expr(e.weight)})"
     if isinstance(e, DotProduct):
-        return f"dot({render_expr(e.a)}, {render_expr(e.b)})"
+        heads = f", heads={e.heads}" if e.heads > 1 else ""
+        return f"dot({render_expr(e.a)}, {render_expr(e.b)}{heads})"
     if isinstance(e, Binary):
         sym = _BINOP_SYMBOL.get(e.op, e.op)
         return f"({render_expr(e.a)} {sym} {render_expr(e.b)})"

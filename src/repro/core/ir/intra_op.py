@@ -90,7 +90,8 @@ class GemmSpec:
     scatter: ScatterScheme
     per_row_scale: Optional[str] = None    # fused epilogue scalar (edge var)
     transpose_w: bool = False
-    out_cols: int = 0                      # N dim of the GEMM
+    out_cols: int = 0                      # N dim of the GEMM (all heads)
+    heads: int = 1                         # per-head weights [T, H, k, n]
     schedule: GemmSchedule = dataclasses.field(default_factory=GemmSchedule)
     preference: Preference = Preference.GEMM
 
@@ -118,6 +119,9 @@ class TraversalStmt:
       'segment_sum'  out[dst] = sum over incoming
       'gather_dst'   out[i] = in[dst[i]]             (dst-indexed read)
       'gather_unique' out[i] = in[edge_to_unique[i]] (compact-layout read)
+      'rowdot'       out[i] = ins[0][i] · ins[1][i], one dot per head's
+                     block of columns when ``heads`` > 1 -> [E, heads]
+      'gather_ntype_weight' out[v] = w[node_type[v]] (node-typed scalar)
     """
 
     kind: str
@@ -127,6 +131,7 @@ class TraversalStmt:
     alpha: float = 0.01
     scale: Optional[str] = None       # for segment_sum: per-edge scalar
     hoist_level: int = 0              # loop level after hoisting (§3.4.1)
+    heads: int = 1                    # for rowdot: column blocks dotted
 
 
 @dataclasses.dataclass
@@ -188,6 +193,7 @@ class Plan:
                 lines.append(
                     f"  GEMM<{o.kid}> {o.out} = {o.x_source}[{o.gather.value}]"
                     f" @ {o.weight}[{o.type_index.value}]"
+                    + (f" heads={o.heads}" if o.heads > 1 else "")
                     + (f" * {o.per_row_scale}" if o.per_row_scale else "")
                     + f" -> scatter:{o.scatter.value} tile={o.schedule.tile_rows}x"
                     f"{o.schedule.tile_cols} coarsen={o.schedule.coarsening}"

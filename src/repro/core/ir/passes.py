@@ -59,7 +59,8 @@ def reorder_linear_ops(prog: I.Program) -> Tuple[I.Program, List[O.WeightProduct
     new_stmts: List[I.Stmt] = []
     counter = 0
     for s in prog.stmts:
-        if isinstance(s, I.EdgeCompute) and isinstance(s.expr, I.DotProduct):
+        if (isinstance(s, I.EdgeCompute) and isinstance(s.expr, I.DotProduct)
+                and s.expr.heads == 1):
             dot = s.expr
             lhs = _resolve(dot.a, defs)
             rhs = dot.b
@@ -174,7 +175,8 @@ def flatten_gemms(prog: I.Program) -> I.Program:
         if isinstance(e, I.Linear):
             return dataclasses.replace(e, x=hoist(e.x, acc, top=False))
         if isinstance(e, I.DotProduct):
-            return I.DotProduct(hoist(e.a, acc, False), hoist(e.b, acc, False))
+            return dataclasses.replace(e, a=hoist(e.a, acc, False),
+                                       b=hoist(e.b, acc, False))
         if isinstance(e, I.Binary):
             return I.Binary(e.op, hoist(e.a, acc, False), hoist(e.b, acc, False))
         if isinstance(e, I.Unary):
@@ -231,6 +233,11 @@ class _ExpandedSoftmax(I.Stmt):
 # ---------------------------------------------------------------------------
 # lowering (§3.2.5): three greedy passes + fusion
 # ---------------------------------------------------------------------------
+def _heads(w: I.Weight) -> int:
+    """Heads of a typed linear's weight: H for per-head (H, k, n), else 1."""
+    return w.shape[0] if len(w.shape) == 3 else 1
+
+
 def _gemm_eligible(stmt: I.Stmt, layouts: Dict[str, I.Layout]) -> Optional[O.GemmSpec]:
     """Pass-1 eligibility: typed/untyped linear over node or edge data."""
     if isinstance(stmt, I.EdgeCompute):
@@ -272,7 +279,7 @@ def _gemm_eligible(stmt: I.Stmt, layouts: Dict[str, I.Layout]) -> Optional[O.Gem
                 kid="", x_source=xsrc, gather=gather, weight=w.name,
                 type_index=tindex, seg_ptr=seg, out=stmt.out,
                 scatter=O.ScatterScheme.IDENTITY, per_row_scale=scale,
-                out_cols=w.shape[-1],
+                out_cols=_heads(w) * w.shape[-1], heads=_heads(w),
             )
         if isinstance(e, I.Linear):
             return O.GemmSpec(
@@ -290,7 +297,7 @@ def _gemm_eligible(stmt: I.Stmt, layouts: Dict[str, I.Layout]) -> Optional[O.Gem
                     kid="", x_source="node:" + e.x.name, gather=O.GatherScheme.BY_NODE,
                     weight=w.name, type_index=O.TypeIndex.NTYPE, seg_ptr="ntype_ptr",
                     out=stmt.out, scatter=O.ScatterScheme.IDENTITY,
-                    out_cols=w.shape[-1],
+                    out_cols=_heads(w) * w.shape[-1], heads=_heads(w),
                 )
         if isinstance(e, I.Linear) and isinstance(e.x, (I.NodeFeature, I.NodeVar)):
             return O.GemmSpec(
@@ -355,7 +362,7 @@ def _expr_to_traversal(
             t = f"{tmp_prefix}_d{counter[0]}"
             stmts.append(O.TraversalStmt("gather_dst", t, ("node:" + e.name,)))
             return t
-        if isinstance(e, I.NodeVar):
+        if isinstance(e, (I.NodeVar, I.NodeFeature)):
             return "node:" + e.name
         if isinstance(e, I.Scalar):
             return f"scalar:{e.value}"
@@ -381,7 +388,8 @@ def _expr_to_traversal(
                 return None
             counter[0] += 1
             t = f"{tmp_prefix}_dp{counter[0]}"
-            stmts.append(O.TraversalStmt("rowdot", t, (a, b)))
+            stmts.append(O.TraversalStmt("rowdot", t, (a, b),
+                                         heads=e.heads))
             return t
         if isinstance(e, I.Concat):
             parts = [emit(p) for p in e.parts]
@@ -396,6 +404,13 @@ def _expr_to_traversal(
             counter[0] += 1
             t = f"{tmp_prefix}_w{counter[0]}"
             stmts.append(O.TraversalStmt("gather_etype_weight", t, (e.name,)))
+            return t
+        if isinstance(e, I.Weight) and e.indexed_by in (
+                "ntype", "ntype_src", "ntype_dst") and e.shape == ():
+            # one scalar per node type, read at each node
+            counter[0] += 1
+            t = f"{tmp_prefix}_w{counter[0]}"
+            stmts.append(O.TraversalStmt("gather_ntype_weight", t, (e.name,)))
             return t
         return None
 

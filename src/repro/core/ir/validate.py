@@ -60,6 +60,7 @@ class _Validator:
         self.edge_vars: Dict[str, Optional[int]] = {}
         self.node_vars: Dict[str, Optional[int]] = {}
         self.inputs: Dict[str, int] = {}    # named input feature -> dim
+        self.linear_inputs = set()          # input features a linear read
         self.i = 0
 
     # ------------------------------------------------------------------
@@ -92,12 +93,17 @@ class _Validator:
                 self.node_vars[s.out] = (
                     self.infer(s.expr) if self.shapes else None)
             elif isinstance(s, I.EdgeSoftmax):
-                self.need_edge_var(s.src, "edge_softmax")
-                self.edge_vars[s.out] = 1
+                # one softmax per score column (per head)
+                self.edge_vars[s.out] = self.need_edge_var(
+                    s.src, "edge_softmax") or 1
             elif isinstance(s, I.NodeAggregate):
                 d = self.need_edge_var(s.msg, "aggregate message")
                 if s.scale is not None:
-                    self.need_edge_var(s.scale, "aggregate scale")
+                    h = self.need_edge_var(s.scale, "aggregate scale")
+                    if self.shapes and d and h and h > 1 and d % h:
+                        self.fail(f"aggregate: message '{s.msg}' has dim "
+                                  f"{d}, which {h} head scales of "
+                                  f"'{s.scale}' do not divide")
                 if s.reduce not in ("sum", "mean"):
                     self.fail(f"unknown aggregate reduce {s.reduce!r}; "
                               f"pick 'sum' or 'mean'")
@@ -132,10 +138,13 @@ class _Validator:
                 self.fail(f"node data n.{e.name} read in a for-each-edge "
                           f"statement; use e.src[{e.name!r}] or "
                           f"e.dst[{e.name!r}]")
-            if domain == "node" and self.domains and not linear_x:
-                # the lowering has no elementwise read of a raw input
-                # feature (it would fall back past the executor), and this
-                # shape is almost always a typo'd produced-var name
+            if linear_x:
+                self.linear_inputs.add(e.name)
+            elif (domain == "node" and self.domains
+                    and e.name not in self.linear_inputs):
+                # an input no linear has read is almost always a typo'd
+                # produced-var name (a gated skip, say, reads the layer
+                # input after its linears have)
                 have = sorted(self.node_vars) or ["<none>"]
                 self.fail(f"input n.{e.name} can only feed a linear ('@') "
                           f"in a for-each-node statement; if you meant a "
@@ -204,13 +213,16 @@ class _Validator:
             xd = self.infer(e.x)
             w = e.weight
             if len(w.shape) >= 2:
+                # per-head (H, k, n): H blocks of k columns in, of n out
+                h = w.shape[0] if len(w.shape) == 3 else 1
+                k = h * w.shape[-2]
                 if xd is None:
-                    self.bind_named(e.x, w.shape[0])
-                elif xd != w.shape[0]:
+                    self.bind_named(e.x, k)
+                elif xd != k:
                     self.fail(f"dim mismatch in '@': left operand "
                               f"({I.render_expr(e.x)}) has dim {xd} but "
-                              f"weight '{w.name}' expects {w.shape[0]}")
-                return w.shape[-1]
+                              f"weight '{w.name}' expects {k}")
+                return h * w.shape[-1]
             return None
         if isinstance(e, I.DotProduct):
             ad, bd = self.infer(e.a), self.infer(e.b)
@@ -222,7 +234,10 @@ class _Validator:
                 self.fail(f"dot() operand dim mismatch: "
                           f"{I.render_expr(e.a)} has dim {ad} but "
                           f"{I.render_expr(e.b)} has dim {bd}")
-            return 1
+            d = ad if ad is not None else bd
+            if d is not None and d % e.heads:
+                self.fail(f"dot(): {e.heads} heads do not divide dim {d}")
+            return e.heads
         if isinstance(e, I.Binary):
             ad, bd = self.infer(e.a), self.infer(e.b)
             if (ad is not None and bd is not None and ad != bd

@@ -30,6 +30,7 @@ from repro.frontend.trace import (  # noqa: F401
     dot,
     edge_softmax,
     exp,
+    gelu,
     leaky_relu,
     model,
     neg,
@@ -43,5 +44,5 @@ __all__ = [
     "model", "compile", "CompiledRGNN", "ModelSpec",
     "ProgramValidationError", "validate_program", "check_var_refs",
     "aggregate", "concat", "dot", "edge_softmax", "unary",
-    "relu", "leaky_relu", "sigmoid", "tanh", "exp", "neg",
+    "relu", "leaky_relu", "sigmoid", "tanh", "exp", "neg", "gelu",
 ]

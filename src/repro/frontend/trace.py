@@ -32,7 +32,10 @@ Semantics of the proxies:
   reads a produced node var, or — if no statement wrote it — an input node
   feature.
 * ``x @ W`` is the typed (or untyped) linear; ``+ - * /`` are elementwise
-  with float->scalar promotion; ``hector.dot`` is the edgewise row dot.
+  with float->scalar promotion; ``hector.dot`` is the edgewise row dot
+  (``heads=H``: one dot per head's block of columns). A weight of per-type
+  shape ``(H, k, n)`` is a per-head linear, and one of shape ``()`` a
+  scalar per type that elementwise ops read at each node or edge.
 * ``hector.edge_softmax`` / ``hector.aggregate`` build the composite
   statements (assign the former to ``e[...]``, the latter to ``n[...]``).
 * ``return n[...]`` (or a tuple of reads) names the program outputs.
@@ -52,7 +55,7 @@ from repro.core.ir.validate import ProgramValidationError, validate_program
 
 __all__ = [
     "model", "ModelSpec", "dot", "concat", "edge_softmax", "aggregate",
-    "unary", "relu", "leaky_relu", "sigmoid", "tanh", "exp", "neg",
+    "unary", "relu", "leaky_relu", "sigmoid", "tanh", "exp", "neg", "gelu",
 ]
 
 
@@ -312,15 +315,19 @@ class NodeProxy:
 # ---------------------------------------------------------------------------
 # DSL operations
 # ---------------------------------------------------------------------------
-def dot(a, b) -> Ex:
-    """Edgewise row dot product -> one scalar per edge (§3.3.1)."""
+def dot(a, b, heads: int = 1) -> Ex:
+    """Edgewise row dot product -> one scalar per edge (§3.3.1); with
+    ``heads`` > 1, one per head's block of columns -> ``heads`` per edge."""
     loc = _user_loc()
     tr = a.trace if isinstance(a, Ex) else (
         b.trace if isinstance(b, (Ex, Wt)) else None)
     if tr is None:
         raise ProgramValidationError(
             "dot() needs traced operands", source=loc)
-    return Ex(I.DotProduct(_as_expr(a, tr, loc), _as_expr(b, tr, loc)), tr)
+    if heads < 1:
+        tr.fail(f"dot(): heads={heads}; need at least 1", loc)
+    return Ex(I.DotProduct(_as_expr(a, tr, loc), _as_expr(b, tr, loc),
+                           int(heads)), tr)
 
 
 def concat(*parts) -> Ex:
@@ -332,10 +339,12 @@ def concat(*parts) -> Ex:
     return Ex(I.Concat(tuple(_as_expr(p, tr, loc) for p in parts)), tr)
 
 
-_UNARY_OPS = ("exp", "leaky_relu", "relu", "sigmoid", "neg", "tanh")
+_UNARY_OPS = ("exp", "leaky_relu", "relu", "sigmoid", "neg", "tanh", "gelu")
 
 
 def _unary(op: str, x, alpha: float, loc: I.SourceLoc) -> Ex:
+    if isinstance(x, Wt):           # a per-type scalar or vector weight
+        x = Ex(x.weight, x.trace)
     if not isinstance(x, Ex):
         raise ProgramValidationError(
             f"{op}() needs a traced operand, got {type(x).__name__}",
@@ -373,6 +382,11 @@ def exp(x) -> Ex:
 
 def neg(x) -> Ex:
     return _unary("neg", x, 0.01, _user_loc())
+
+
+def gelu(x) -> Ex:
+    """Exact GELU, x Φ(x) (``jax.nn.gelu(approximate=False)``)."""
+    return _unary("gelu", x, 0.01, _user_loc())
 
 
 def edge_softmax(score) -> _EdgeSoftmaxMarker:

@@ -292,36 +292,79 @@ def _make_pallas_segment_mm(tile_rows: int, tile_n: int, num_groups: int,
     return f
 
 
+def _padded_mm(x_p, w, t2g, scale_p, tile_rows, num_groups, backend,
+               tile_n):
+    """The GEMM template over tile-padded rows: ``Y_p = X_p @ W[t2g]``."""
+    if backend == "xla":
+        return _segment_mm_xla_padded(x_p, w, t2g, scale_p, tile_rows)
+    interpret = backend == "pallas_interpret"
+    tn = _fit_tile_n(w.shape[-1], tile_n)
+    f = _make_pallas_segment_mm(tile_rows, tn, num_groups,
+                                scale_p is not None, interpret)
+    if scale_p is None:
+        scale_p = jnp.ones((x_p.shape[0], 1), x_p.dtype)
+    return f(x_p, w, scale_p, t2g)
+
+
+def _count_heads(op: str, heads: int) -> None:
+    """Runs where a per-head op is traced (on the host, once per trace)."""
+    obs.metrics().counter("heads_traced", op=op, heads=str(heads)).inc()
+
+
+def _heads_major(x: jnp.ndarray, heads: int) -> jnp.ndarray:
+    """``[M, H*d]`` -> ``[H*M, d]``: head ``h`` of row ``m`` becomes row
+    ``h*M + m``."""
+    m = x.shape[0]
+    return x.reshape(m, heads, -1).transpose(1, 0, 2).reshape(heads * m, -1)
+
+
+def _rows_major(y: jnp.ndarray, heads: int) -> jnp.ndarray:
+    """``[H*M, d]`` -> ``[M, H*d]``, the inverse of ``_heads_major``."""
+    m = y.shape[0] // heads
+    return y.reshape(heads, m, -1).transpose(1, 0, 2).reshape(m, -1)
+
+
 def segment_mm(
-    x_sorted: jnp.ndarray,                  # [M, k] type-sorted rows
-    w: jnp.ndarray,                         # [R, k, n]
+    x_sorted: jnp.ndarray,                  # [M, k] or [M, H*k] type-sorted
+    w: jnp.ndarray,                         # [R, k, n] or [R, H, k, n]
     lay: PaddedSegmentsDev,
     row_scale: Optional[jnp.ndarray] = None,  # [M]
     backend: Backend = "xla",
     tile_n: int = 128,
     tile_rows: Optional[int] = None,        # sub-tile of lay.tile (tuner knob)
 ) -> jnp.ndarray:
-    """Y = X @ W[type] (+ per-row scale), X presorted by type. -> [M, n]."""
+    """Y = X @ W[type] (+ per-row scale), X presorted by type. -> [M, n].
+
+    With per-head weights ``[R, H, k, n]`` each row's ``h``-th ``k``-wide
+    block is multiplied by its type's ``[k, n]`` matrix of head ``h``
+    -> ``[M, H*n]``. The heads become rows: padded rows go head-major
+    (``[H*Rp, k]``), each head's tiles keep their type and select weight
+    group ``h*R + type``, and the one GEMM template runs over all of them,
+    so no block-diagonal matrix is formed."""
     if x_sorted.shape[0] == 0:
         # empty block (e.g. a sampled hop with no edges): no tiles to sweep
-        return jnp.zeros((0, w.shape[-1]), x_sorted.dtype)
+        n = w.shape[-1] * (w.shape[1] if w.ndim == 4 else 1)
+        return jnp.zeros((0, n), x_sorted.dtype)
     x_p = pad_rows(x_sorted, lay)
     scale_p = None
     if row_scale is not None:
         scale_p = pad_rows(row_scale, lay)[:, None]
     tr = _fit_tile_rows(lay.tile, tile_rows)
     t2g = _subtile_t2g(lay.t2g, lay.tile, tr)
-    if backend == "xla":
-        y_p = _segment_mm_xla_padded(x_p, w, t2g, scale_p, tr)
-    else:
-        interpret = backend == "pallas_interpret"
-        tn = _fit_tile_n(w.shape[-1], tile_n)
-        f = _make_pallas_segment_mm(tr, tn, lay.num_groups,
-                                    scale_p is not None, interpret)
-        if scale_p is None:
-            scale_p = jnp.ones((x_p.shape[0], 1), x_p.dtype)
-        y_p = f(x_p, w, scale_p, t2g)
-    return unpad_rows(y_p, lay)
+    if w.ndim == 3:
+        y_p = _padded_mm(x_p, w, t2g, scale_p, tr, lay.num_groups, backend,
+                         tile_n)
+        return unpad_rows(y_p, lay)
+    r, heads = w.shape[:2]
+    _count_heads("gemm", heads)
+    w_h = w.transpose(1, 0, 2, 3).reshape(heads * r, *w.shape[2:])
+    t2g_h = (t2g[None, :] + r * jnp.arange(heads, dtype=t2g.dtype)[:, None]
+             ).reshape(-1)
+    if scale_p is not None:
+        scale_p = jnp.tile(scale_p, (heads, 1))
+    y_h = _padded_mm(_heads_major(x_p, heads), w_h, t2g_h, scale_p, tr,
+                     heads * r, backend, tile_n)
+    return unpad_rows(_rows_major(y_h, heads), lay)
 
 
 def gather_mm(
@@ -566,8 +609,55 @@ def _msg_slot_map(bc: BlockedCSRDev,
         bc.edge_map >= 0, msg_rows[jnp.maximum(bc.edge_map, 0)], -1)
 
 
+def _shift(idx: jnp.ndarray, heads: int, stride: int) -> jnp.ndarray:
+    """An index map repeated per head, head ``h`` shifted by ``h*stride``
+    (-1 entries stay -1)."""
+    off = stride * jnp.arange(heads, dtype=idx.dtype)[:, None]
+    return jnp.where(idx[None] >= 0, idx[None] + off, -1).reshape(-1)
+
+
+def _per_head(fn, op: str, scores, msg, dst, num_nodes, bc, backend,
+              msg_rows, msg_slot_map, fuse_gather) -> jnp.ndarray:
+    """Run the one-head traversal op ``fn`` over ``[E, H]`` score columns
+    as one graph of ``H * Np`` destinations (``Np`` the blocked layout's
+    padded node count): head ``h`` of node ``v`` is node ``h*Np + v``, of
+    edge ``e`` edge ``h*E + e``, of message row ``i`` row ``h*Em + i``
+    (its ``h``-th ``d/H`` columns). The blocked layout repeats once per
+    head with its node blocks shifted by ``h*Np``, so the kernels and their
+    VJPs run the heads as they run nodes."""
+    heads = scores.shape[1]
+    _count_heads(op, heads)
+    e, em = dst.shape[0], msg.shape[0]
+    np_ = bc.num_node_blocks * bc.node_block
+    bc_h = BlockedCSRDev(
+        edge_map=_shift(bc.edge_map, heads, e),
+        edge_map_unique=_shift(bc.edge_map_unique, heads, em),
+        local_dst=jnp.tile(bc.local_dst, (heads, 1)),
+        t2b=(bc.t2b[None] + bc.num_node_blocks * jnp.arange(
+            heads, dtype=bc.t2b.dtype)[:, None]).reshape(-1),
+        edge_tile=bc.edge_tile, node_block=bc.node_block,
+        num_node_blocks=heads * bc.num_node_blocks,
+        num_nodes=heads * np_)
+    rows = None if msg_rows is None else _shift(msg_rows, heads, em)
+    slots = None if msg_slot_map is None else _shift(msg_slot_map, heads, em)
+    out = fn(scores.T.reshape(-1), _heads_major(msg, heads),
+             _shift(dst, heads, np_), heads * np_, bc=bc_h, backend=backend,
+             msg_rows=rows, msg_slot_map=slots, fuse_gather=fuse_gather)
+    out = out.reshape(heads, np_, -1)[:, :num_nodes]
+    return _rows_major(out.reshape(heads * num_nodes, -1), heads)
+
+
+def _heads_ref(agg, scores, msg, msg_rows, dst, num_nodes):
+    """XLA formulation with H score columns: head ``h`` of a message is its
+    ``h``-th block of ``d/H`` columns."""
+    msg_e = msg if msg_rows is None else msg[msg_rows]
+    out = agg(scores, msg_e.reshape(msg_e.shape[0], scores.shape[1], -1),
+              dst, num_nodes)
+    return out.reshape(num_nodes, -1)
+
+
 def edge_softmax_agg(
-    scores: jnp.ndarray,        # [E] canonical order
+    scores: jnp.ndarray,        # [E] canonical order, or [E, H] per head
     msg: jnp.ndarray,           # [Em, d] in storage order (see msg_rows)
     dst: jnp.ndarray,           # [E] canonical destination ids
     num_nodes: int,
@@ -585,9 +675,21 @@ def edge_softmax_agg(
     inside the kernel via the slot map, so no dst-sorted ``[Ep, d]`` copy is
     materialized. ``fuse_gather=False`` keeps the materialized-gather kernel
     (equivalence baseline).
+
+    ``[E, H]`` scores are H heads: softmax per column, and head ``h``
+    weighs the ``h``-th ``d/H``-wide block of each message. On the Pallas
+    backends the heads run as the nodes of one larger graph
+    (``_per_head``).
     """
     if dst.shape[0] == 0:
         return jnp.zeros((num_nodes, msg.shape[-1]), msg.dtype)
+    if scores.ndim == 2:
+        if backend == "xla" or bc is None:
+            return _heads_ref(R.softmax_agg_ref, scores, msg, msg_rows, dst,
+                              num_nodes)
+        return _per_head(edge_softmax_agg, "softmax_agg", scores, msg, dst,
+                         num_nodes, bc, backend, msg_rows, msg_slot_map,
+                         fuse_gather)
     if backend == "xla" or bc is None:
         msg_e = msg if msg_rows is None else msg[msg_rows]
         return R.softmax_agg_ref(scores, msg_e, dst, num_nodes)
@@ -706,9 +808,17 @@ def weighted_agg(
     msg_slot_map: Optional[jnp.ndarray] = None,
     fuse_gather: bool = True,
 ) -> jnp.ndarray:
-    """out[v] = Σ_{e→v} scale_e · msg_e (gather semantics as edge_softmax_agg)."""
+    """out[v] = Σ_{e→v} scale_e · msg_e (gather semantics as edge_softmax_agg;
+    an ``[E, H]`` scale weighs each message's H column blocks)."""
     if dst.shape[0] == 0:
         return jnp.zeros((num_nodes, msg.shape[-1]), msg.dtype)
+    if scale is not None and scale.ndim == 2:
+        if backend == "xla" or bc is None:
+            return _heads_ref(R.weighted_agg_ref, scale, msg, msg_rows, dst,
+                              num_nodes)
+        return _per_head(weighted_agg, "weighted_agg", scale, msg, dst,
+                         num_nodes, bc, backend, msg_rows, msg_slot_map,
+                         fuse_gather)
     if backend == "xla" or bc is None:
         msg_e = msg if msg_rows is None else msg[msg_rows]
         return R.weighted_agg_ref(scale, msg_e, dst, num_nodes)
@@ -734,5 +844,6 @@ def weighted_agg(
 
 def edge_softmax(scores: jnp.ndarray, dst: jnp.ndarray,
                  num_nodes: int) -> jnp.ndarray:
-    """Per-edge stabilized softmax over incoming-edge groups (XLA)."""
+    """Per-edge stabilized softmax over incoming-edge groups (XLA); ``[E,
+    H]`` scores take one softmax per column."""
     return R.edge_softmax_ref(scores, dst, num_nodes)

@@ -45,15 +45,27 @@ def edge_softmax_ref(scores: jnp.ndarray, dst: jnp.ndarray, num_nodes: int):
     return jnp.exp(scores - mx[dst]) / jnp.maximum(den[dst], 1e-38)
 
 
+def segment_mm_heads_ref(x: jnp.ndarray, w: jnp.ndarray,
+                         seg_ids: jnp.ndarray) -> jnp.ndarray:
+    """Per-head typed linear: y[i, h] = x[i, h] @ w[seg_ids[i], h].
+
+    x: [M, H*k]; w: [R, H, k, n] -> [M, H*n]."""
+    m, (_, h, k, _) = x.shape[0], w.shape
+    y = jnp.einsum("mhk,mhkn->mhn", x.reshape(m, h, k), w[seg_ids])
+    return y.reshape(m, -1)
+
+
 def softmax_agg_ref(scores: jnp.ndarray, msg: jnp.ndarray, dst: jnp.ndarray,
                     num_nodes: int) -> jnp.ndarray:
-    """out[v] = sum_{e: dst(e)=v} softmax(scores)_e * msg[e]."""
+    """out[v] = sum_{e: dst(e)=v} softmax(scores)_e * msg[e]; with scores
+    [E, H] and msg [E, H, d], one softmax per head -> [N, H, d]."""
     att = edge_softmax_ref(scores, dst, num_nodes)
-    return compat.segment_sum(att[:, None] * msg, dst, num_nodes)
+    return compat.segment_sum(att[..., None] * msg, dst, num_nodes)
 
 
 def weighted_agg_ref(scale: jnp.ndarray | None, msg: jnp.ndarray,
                      dst: jnp.ndarray, num_nodes: int) -> jnp.ndarray:
-    """out[v] = sum_{e: dst(e)=v} scale_e * msg[e] (plain traversal agg)."""
-    contrib = msg if scale is None else scale[:, None] * msg
+    """out[v] = sum_{e: dst(e)=v} scale_e * msg[e] (plain traversal agg);
+    scale [E, H] weighs msg [E, H, d] per head."""
+    contrib = msg if scale is None else scale[..., None] * msg
     return compat.segment_sum(contrib, dst, num_nodes)

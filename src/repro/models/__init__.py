@@ -1,6 +1,7 @@
 from repro.models.rgcn import rgcn, rgcn_program          # noqa: F401
 from repro.models.rgat import rgat, rgat_program          # noqa: F401
-from repro.models.hgt import hgt, hgt_program             # noqa: F401
+from repro.models.hgt import (hgt, hgt_program,                   # noqa: F401
+                              hgt_published, hgt_published_program)
 from repro.models.zoo import rgcn_cat, rgcn_cat_program   # noqa: F401
 
 # the DSL ModelSpecs, keyed as the drivers' --model flag expects

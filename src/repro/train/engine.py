@@ -22,12 +22,13 @@ import jax.numpy as jnp
 from repro import obs
 from repro.core.graph import HeteroGraph
 from repro.core.module import HectorStack
-from repro.models import (hgt_program, rgat_program, rgcn_cat_program,
-                          rgcn_program)
+from repro.models import (hgt_program, hgt_published_program, rgat_program,
+                          rgcn_cat_program, rgcn_program)
 from repro.sampling import DeviceSampler, FanoutSampler, MiniBatchLoader
 
 MODEL_PROGRAMS = {"rgcn": rgcn_program, "rgat": rgat_program,
-                  "hgt": hgt_program, "rgcn_cat": rgcn_cat_program}
+                  "hgt": hgt_program, "rgcn_cat": rgcn_cat_program,
+                  "hgt_published": hgt_published_program}
 
 
 def parse_fanout(spec: str, layers: int) -> List[int]:

@@ -135,7 +135,7 @@ def test_scope_names_never_match_a_kernel_name(graph):
     names = json.loads((pathlib.Path(__file__).parents[1] / "bench" /
                         "metrics" / "kernel_names.json").read_text())
     patterns = [re.compile(p) for ps in names.values() for p in ps]
-    for model in ("rgat", "rgcn", "hgt", "rgcn_cat"):
+    for model in ("rgat", "rgcn", "hgt", "rgcn_cat", "hgt_published"):
         eng = RGNNEngine(graph, EngineConfig(model=model, layers=2, dim=8,
                                              hidden=8, classes=4))
         for i, plan in enumerate(eng.plans):
@@ -144,6 +144,24 @@ def test_scope_names_never_match_a_kernel_name(graph):
             for s in scopes:
                 assert device_ops.OWNERS.match(s), s
                 assert not any(p.search(s) for p in patterns), s
+
+
+def test_hgt_published_owner_kinds(graph):
+    """HGT's node-typed GEMMs (K, Q, V, A-Linear) own their device work
+    under the scope kind ``nodegemm``, its per-head relation GEMMs under
+    ``gemm``, the edge scores, softmax and aggregation under
+    ``traversal`` and the GELU under ``nodewise`` (its derivative fuses
+    into the A-Linear's backward)."""
+    import functools
+    from repro.models import hgt_published_program
+    text, _ = _compile_train_step(
+        graph, functools.partial(hgt_published_program, heads=4))
+    _, table = device_ops.parse_hlo(text)
+    kinds = {(o.owner.split(".")[1], o.direction) for o in table.values()
+             if o is not None and re.match(r"l\d+\.", o.owner)}
+    for kind in ("nodegemm", "gemm", "traversal"):
+        assert {(kind, "forward"), (kind, "backward")} <= kinds, kind
+    assert ("nodewise", "forward") in kinds
 
 
 def test_op_name_parsing():

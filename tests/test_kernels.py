@@ -473,3 +473,164 @@ def test_padded_perm_traced_counter(rng):
         assert reg.counter("padded_perm_traced", op="unpad").value == 1
         jax.jit(lambda x, lay: ops.segment_mm(x, w, lay))(x, lay)
         assert reg.counter_total("padded_perm_traced") == 5
+
+
+# ---------------------------------------------------------------------------
+# attention heads: per-head GEMM, edge softmax and aggregation
+# (``ops`` folds H heads into rows: head-major GEMM rows, and one graph of
+# H * Np destinations for the traversal kernels)
+def _same_bits(a, b):
+    np.testing.assert_array_equal(_bits(a), _bits(b))
+
+
+def _heads_gemm_case(rng, heads, k=4, n=3):
+    ptr, seg_ids, m = _segments(rng, 5, 13)
+    x = jnp.asarray(rng.normal(size=(m, heads * k)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(5, heads, k, n)), jnp.float32)
+    s = jnp.asarray(rng.normal(size=(m,)), jnp.float32)
+    lay = ops.padded_segments_dev(L.pad_segments(ptr, 8))
+    return x, w, s, lay, jnp.asarray(seg_ids)
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("heads", [2, 4])
+@pytest.mark.parametrize("with_scale", [False, True])
+def test_segment_mm_heads_matches_ref(rng, backend, heads, with_scale):
+    """Each row's h-th block times its type's matrix of head h, forward and
+    VJP, against ``ref.segment_mm_heads_ref``. Tolerances as the one-head
+    GEMM tests': float32 sums in another order."""
+    x, w, s, lay, seg = _heads_gemm_case(rng, heads)
+    s = s if with_scale else None
+
+    def f(x, w):
+        return ops.segment_mm(x, w, lay, row_scale=s, backend=backend)
+
+    def f_ref(x, w):
+        y = R.segment_mm_heads_ref(x, w, seg)
+        return y if s is None else y * s[:, None]
+
+    np.testing.assert_allclose(f(x, w), f_ref(x, w), rtol=1e-5, atol=1e-5)
+    g = jax.grad(lambda *a: jnp.sum(jnp.sin(f(*a))), argnums=(0, 1))(x, w)
+    gr = jax.grad(lambda *a: jnp.sum(jnp.sin(f_ref(*a))),
+                  argnums=(0, 1))(x, w)
+    for a, b in zip(g, gr):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_segment_mm_one_head_is_todays_kernel(rng, backend):
+    """One head through the per-head path equals the one-head GEMM bit for
+    bit, forward and VJP."""
+    x, w, s, lay, _ = _heads_gemm_case(rng, 1, k=6, n=5)
+
+    def f(x, w):
+        return jnp.sum(jnp.sin(ops.segment_mm(x, w, lay, row_scale=s,
+                                              backend=backend)))
+
+    w3 = w[:, 0]
+    _same_bits(ops.segment_mm(x, w, lay, row_scale=s, backend=backend),
+               ops.segment_mm(x, w3, lay, row_scale=s, backend=backend))
+    gx4, gw4 = jax.grad(f, argnums=(0, 1))(x, w)
+    gx3, gw3 = jax.grad(f, argnums=(0, 1))(x, w3)
+    _same_bits(gx4, gx3)
+    _same_bits(gw4[:, 0], gw3)
+
+
+def _heads_traversal_case(rng, heads, compact, n_nodes=13, n_edges=70, d=3):
+    dst, bc = _dst_layout(rng, n_nodes, n_edges)
+    scores = jnp.asarray(rng.normal(size=(n_edges, heads)), jnp.float32)
+    n_rows = 20 if compact else n_edges
+    msg = jnp.asarray(rng.normal(size=(n_rows, heads * d)), jnp.float32)
+    rows = jnp.asarray(rng.integers(0, n_rows, n_edges), jnp.int32) \
+        if compact else None
+    return dst, bc, scores, msg, rows
+
+
+def _per_head_ref(agg, scores, msg, rows, dst, n_nodes):
+    msg_e = msg if rows is None else msg[rows]
+    out = agg(scores, msg_e.reshape(msg_e.shape[0], scores.shape[1], -1),
+              dst, n_nodes)
+    return out.reshape(n_nodes, -1)
+
+
+_TRAVERSALS = {"softmax_agg": (ops.edge_softmax_agg, R.softmax_agg_ref),
+               "weighted_agg": (ops.weighted_agg, R.weighted_agg_ref)}
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("op", sorted(_TRAVERSALS))
+@pytest.mark.parametrize("compact", [False, True])
+@pytest.mark.parametrize("fuse", [False, True])
+def test_traversal_heads_matches_ref(rng, backend, op, compact, fuse):
+    """H = 3 score columns, each weighing its block of message columns,
+    forward and VJP against ``kernels/ref.py`` (one softmax per column);
+    vanilla and compact messages, gather fused or not. Tolerances as the
+    one-head traversal tests': float32 sums in another order."""
+    fn, ref = _TRAVERSALS[op]
+    dst, bc, scores, msg, rows = _heads_traversal_case(rng, 3, compact)
+
+    def f(s, m):
+        return fn(s, m, dst, 13, bc=bc, backend=backend, msg_rows=rows,
+                  fuse_gather=fuse)
+
+    def f_ref(s, m):
+        return _per_head_ref(ref, s, m, rows, dst, 13)
+
+    np.testing.assert_allclose(f(scores, msg), f_ref(scores, msg),
+                               rtol=1e-5, atol=1e-5)
+    g = jax.grad(lambda *a: jnp.sum(jnp.cos(f(*a))), argnums=(0, 1))(
+        scores, msg)
+    gr = jax.grad(lambda *a: jnp.sum(jnp.cos(f_ref(*a))), argnums=(0, 1))(
+        scores, msg)
+    for a, b in zip(g, gr):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("op", sorted(_TRAVERSALS))
+@pytest.mark.parametrize("compact", [False, True])
+def test_traversal_one_head_is_todays_kernel(rng, backend, op, compact):
+    """One score column through the per-head path (heads folded into the
+    node axis) equals the one-head traversal bit for bit, forward and
+    VJP."""
+    fn, _ = _TRAVERSALS[op]
+    dst, bc, scores, msg, rows = _heads_traversal_case(rng, 1, compact)
+
+    def f(s, m):
+        return fn(s, m, dst, 13, bc=bc, backend=backend, msg_rows=rows)
+
+    _same_bits(f(scores, msg), f(scores[:, 0], msg))
+    loss = lambda *a: jnp.sum(jnp.cos(f(*a)))  # noqa: E731
+    gs2, gm2 = jax.grad(loss, argnums=(0, 1))(scores, msg)
+    gs1, gm1 = jax.grad(loss, argnums=(0, 1))(scores[:, 0], msg)
+    _same_bits(gs2[:, 0], gs1)
+    _same_bits(gm2, gm1)
+
+
+def test_edge_softmax_heads_is_per_column(rng):
+    dst, _, scores, _, _ = _heads_traversal_case(rng, 4, False)
+    att = ops.edge_softmax(scores, dst, 13)
+    for h in range(4):
+        np.testing.assert_array_equal(
+            att[:, h], R.edge_softmax_ref(scores[:, h], dst, 13))
+
+
+def test_heads_traced_counter(rng):
+    """One count per per-head op traced, labelled with its head count."""
+    from repro import obs
+    x, w, _, lay, _ = _heads_gemm_case(rng, 2)
+    dst, bc, scores, msg, _ = _heads_traversal_case(rng, 3, False)
+    with obs.scope() as st:
+        f = jax.jit(lambda x, w: ops.segment_mm(x, w, lay,
+                                                backend="pallas_interpret"))
+        f(x, w)
+        f(x, w)
+        ops.edge_softmax_agg(scores, msg, dst, 13, bc=bc,
+                             backend="pallas_interpret")
+        ops.edge_softmax_agg(scores[:, 0], msg[:, :3], dst, 13, bc=bc,
+                             backend="pallas_interpret")
+        reg = st.registry
+        assert reg.counter("heads_traced", op="gemm", heads="2").value == 1
+        assert reg.counter("heads_traced", op="softmax_agg",
+                           heads="3").value == 1
+        assert reg.counter_total("heads_traced") == 2
