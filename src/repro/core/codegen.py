@@ -41,8 +41,10 @@ class KernelLayouts:
 
     Besides the segment/CSR layouts this carries the *padded gather-index
     layouts* (§3.3 access schemes composed with the tile padding maps), so
-    the Pallas kernels can gather their input rows in-kernel, and the
-    precomputed per-destination in-degree used by mean aggregation.
+    the Pallas kernels can gather their input rows in-kernel, the
+    precomputed per-destination in-degree used by mean aggregation, and the
+    message-row layout over which the backward of an unfused compact
+    message gather sums (None where no such backward is built).
     Registered as a pytree (metadata static) so whole plans can be jitted
     with the layouts as run-time arguments.
     """
@@ -55,10 +57,12 @@ class KernelLayouts:
     edge_dst_rows: jnp.ndarray         # [Rp_e] padded slot -> dst node, or -1
     unique_src_rows: jnp.ndarray       # [Rp_u] padded slot -> src node, or -1
     dst_deg: jnp.ndarray               # [N] float32 per-destination in-degree
+    msg_rev: Optional[K.BlockedCSRDev] = None  # edges sorted by unique row
 
 
 _KL_FIELDS = ("edge_seg", "unique_seg", "node_seg", "blocked",
-              "edge_src_rows", "edge_dst_rows", "unique_src_rows", "dst_deg")
+              "edge_src_rows", "edge_dst_rows", "unique_src_rows", "dst_deg",
+              "msg_rev")
 
 jtu.register_pytree_node(
     KernelLayouts,
@@ -80,7 +84,9 @@ def build_kernel_layouts(
     (node, edge, unique) bucket combination can still disagree here.
     ``row_floors`` (a ``bucketing.LayoutRowFloors``) clamps each field's
     bucket to a grow-only floor shared across blocks, pinning the layout
-    shapes the way ``pad_block_graph`` targets pin the graph shapes."""
+    shapes the way ``pad_block_graph`` targets pin the graph shapes.
+    Bucketed layouts serve sampled blocks, whose aggregations gather
+    in-kernel, and carry no message-row layout."""
     edge_ps = L.pad_segments(hg.etype_ptr, tile)
     unique_ps = L.pad_segments(hg.unique_etype_ptr, tile)
     node_ps = L.pad_segments(hg.ntype_ptr, tile)
@@ -111,6 +117,8 @@ def build_kernel_layouts(
         unique_src_rows=jnp.asarray(
             L.compose_gather_rows(unique_ps, hg.unique_src)),
         dst_deg=jnp.asarray(np.diff(hg.dst_ptr).astype(np.float32)),
+        msg_rev=None if bucket else K.msg_rev_dev(
+            hg.edge_to_unique, hg.num_unique, tile),
     )
 
 
@@ -477,11 +485,12 @@ def _edge_msg(env: _Env, gt: GraphTensors, kl: KernelLayouts, name: str):
     """Resolve a feature-wide edge var in its *storage* order for the
     traversal kernels: COMPACT vars stay in the unique-pair table and carry
     the precomposed slot map, so the per-edge expansion happens in-kernel
-    instead of materializing an [E, d] copy here."""
+    instead of materializing an [E, d] copy here. -> (messages, msg_rows,
+    slot map, message-row layout)."""
     v = env.get(name)
     if env.plan.layouts.get(name) == I.Layout.COMPACT:
-        return v, gt.edge_to_unique, kl.blocked.edge_map_unique
-    return v, None, kl.blocked.edge_map
+        return v, gt.edge_to_unique, kl.blocked.edge_map_unique, kl.msg_rev
+    return v, None, kl.blocked.edge_map, None
 
 
 def _edge_scalars(v: jnp.ndarray) -> jnp.ndarray:
@@ -512,7 +521,8 @@ def _exec_traversal(op: O.TraversalSpec, env: _Env, gt: GraphTensors,
                 and nxt.kind == "segment_sum"
                 and nxt.scale == att_name
             ):
-                msg, msg_rows, slot_map = _edge_msg(env, gt, kl, nxt.ins[0])
+                msg, msg_rows, slot_map, rev = _edge_msg(env, gt, kl,
+                                                         nxt.ins[0])
                 dec = _trav_decision(decisions, "softmax_agg", msg,
                                      msg_rows is not None, kl) \
                     if heads == 1 else None
@@ -527,7 +537,7 @@ def _exec_traversal(op: O.TraversalSpec, env: _Env, gt: GraphTensors,
                         scores, msg, gt.dst, gt.num_nodes,
                         bc=kl.blocked, backend=backend_eff,
                         msg_rows=msg_rows, msg_slot_map=slot_map,
-                        fuse_gather=fuse,
+                        fuse_gather=fuse, msg_rev=rev,
                     )
                     env.set(nxt.out, out)
                     env.set(att_name,
@@ -571,7 +581,7 @@ def _exec_traversal(op: O.TraversalSpec, env: _Env, gt: GraphTensors,
             mx = compat.segment_max(x, gt.dst, gt.num_nodes)
             env.set(s.out, jnp.where(jnp.isfinite(mx), mx, 0.0))
         elif s.kind == "segment_sum":
-            msg, msg_rows, slot_map = _edge_msg(env, gt, kl, s.ins[0])
+            msg, msg_rows, slot_map, rev = _edge_msg(env, gt, kl, s.ins[0])
             scale = None
             if s.scale is not None:
                 scale = _edge_scalars(env.get_edge_vanilla(s.scale))
@@ -587,7 +597,7 @@ def _exec_traversal(op: O.TraversalSpec, env: _Env, gt: GraphTensors,
             out = K.weighted_agg(scale, msg, gt.dst, gt.num_nodes,
                                  bc=kl.blocked, backend=backend_eff,
                                  msg_rows=msg_rows, msg_slot_map=slot_map,
-                                 fuse_gather=fuse)
+                                 fuse_gather=fuse, msg_rev=rev)
             if s.op == "mean":
                 deg = kl.dst_deg.astype(out.dtype)
                 out = out / jnp.maximum(deg, 1.0)[:, None]

@@ -89,6 +89,9 @@ def build_fixed_layouts(hg: HeteroGraph, tile: int = 128,
     node_ps = L.pad_segments_rows(
         L.pad_segments(hg.ntype_ptr, tile), up(hg.num_nodes) + num_t * tile)
     nb = -(-hg.num_nodes // node_block)
+    # message-row blocks, each at most one tile of padding
+    rev_nb = -(-hg.num_unique // L.msg_row_block(hg.num_edges,
+                                                   hg.num_unique, tile))
     bc = L.pad_blocked_csr(
         L.block_csr(hg.dst_ptr, edge_tile=tile, node_block=node_block),
         up(hg.num_edges) + nb * tile)
@@ -102,6 +105,8 @@ def build_fixed_layouts(hg: HeteroGraph, tile: int = 128,
         unique_src_rows=jnp.asarray(
             L.compose_gather_rows(unique_ps, hg.unique_src)),
         dst_deg=jnp.asarray(np.diff(hg.dst_ptr).astype(np.float32)),
+        msg_rev=K.msg_rev_dev(hg.edge_to_unique, hg.num_unique, tile,
+                              target_edges=up(hg.num_edges) + tile * rev_nb),
     )
 
 

@@ -14,6 +14,8 @@ adaptation moves irregularity to **block granularity**:
   padded so no edge tile spans two destination-node blocks; a
   ``tile_to_block`` map lets consecutive edge tiles accumulate into the same
   output node block in VMEM (deterministic replacement for GPU atomics).
+  ``msg_rows_csr`` builds the same layout over edges sorted by the compact
+  message row they read, for the backward's sum by message row.
 
 Both are computed once per graph on the host (NumPy) and are *data layout
 choices* in the sense of §3.2.2 — the inter-op IR never sees them.
@@ -184,7 +186,10 @@ class BlockedCSR:
         return self.padded_edges // self.edge_tile
 
 
-def block_csr(dst_ptr: np.ndarray, edge_tile: int, node_block: int) -> BlockedCSR:
+def block_csr(dst_ptr: np.ndarray, edge_tile: int, node_block: int,
+              min_tiles: int = 0) -> BlockedCSR:
+    """``min_tiles=1`` gives a node block with no edges one tile of pads,
+    so an accumulating kernel visits, and zeroes, every output block."""
     dst_ptr = np.asarray(dst_ptr, dtype=np.int64)
     num_nodes = len(dst_ptr) - 1
     nb = (num_nodes + node_block - 1) // node_block
@@ -192,7 +197,8 @@ def block_csr(dst_ptr: np.ndarray, edge_tile: int, node_block: int) -> BlockedCS
     blk_start = dst_ptr[np.minimum(np.arange(nb) * node_block, num_nodes)]
     blk_end = dst_ptr[np.minimum((np.arange(nb) + 1) * node_block, num_nodes)]
     sizes = blk_end - blk_start
-    padded = ((sizes + edge_tile - 1) // edge_tile) * edge_tile
+    padded = np.maximum((sizes + edge_tile - 1) // edge_tile,
+                        min_tiles) * edge_tile
     ep = int(padded.sum())
     edge_map = np.full(ep, -1, dtype=np.int32)
     local_dst = np.full(ep, node_block, dtype=np.int32)  # pads point past block
@@ -219,6 +225,34 @@ def block_csr(dst_ptr: np.ndarray, edge_tile: int, node_block: int) -> BlockedCS
         padded_edges=ep, edge_map=edge_map, local_dst=local_dst,
         tile_to_block=t2b, num_node_blocks=nb,
     )
+
+
+def msg_row_block(num_edges: int, num_rows: int, edge_tile: int) -> int:
+    """Rows per block of the message-row layout: the fewest (128 times a
+    power of two, so a block is whole lane tiles) at which the expected
+    tile padding, half an edge tile a block, stays within a tenth of the
+    edges; at most one block of every row. Read from the edges per row
+    alone, so graphs of equal sizes get equal layouts."""
+    rb = 128
+    while (rb < num_rows and
+           edge_tile / 2 * -(-num_rows // rb) > 0.1 * num_edges):
+        rb *= 2
+    return rb
+
+
+def msg_rows_csr(msg_rows: np.ndarray, num_rows: int, edge_tile: int):
+    """-> ``(BlockedCSR, perm)``: the edges sorted (stably) by the compact
+    message row each reads, blocked as ``block_csr`` blocks destinations,
+    with the message rows as the nodes. ``perm`` maps a sorted edge to its
+    canonical index; compose with it as ``ops.blocked_csr_dev`` does with
+    ``perm_dst``. Every row block owns at least one tile, so rows that no
+    edge reads sum to zero."""
+    msg_rows = np.asarray(msg_rows)
+    perm = np.argsort(msg_rows, kind="stable").astype(np.int32)
+    ptr = np.zeros(num_rows + 1, np.int64)
+    np.cumsum(np.bincount(msg_rows, minlength=num_rows), out=ptr[1:])
+    rb = msg_row_block(len(msg_rows), num_rows, edge_tile)
+    return block_csr(ptr, edge_tile, rb, min_tiles=1), perm
 
 
 # ---------------------------------------------------------------------------

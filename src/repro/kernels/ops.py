@@ -63,6 +63,10 @@ jtu.register_pytree_node(
 
 @dataclasses.dataclass(frozen=True, eq=False)
 class BlockedCSRDev:
+    """A blocked CSR layout on the device: edges sorted by destination
+    (``blocked``), or by the message row they read (``msg_rev``, whose
+    "nodes" are the compact message rows)."""
+
     edge_map: jnp.ndarray         # [Ep] canonical edge index or -1
     edge_map_unique: jnp.ndarray  # [Ep] compact (unique-pair) row or -1
     local_dst: jnp.ndarray        # [T, tile]
@@ -101,16 +105,14 @@ def blocked_csr_dev(
     map (``edge_map_unique``), so traversal kernels can gather COMPACT-layout
     messages straight from the unique-pair table in-kernel.
     """
-    edge_map = np.where(
-        bc.edge_map >= 0, np.asarray(perm_dst)[np.maximum(bc.edge_map, 0)], -1
-    ).astype(np.int32)
+    valid = bc.edge_map >= 0
+    edge_map = np.full(bc.edge_map.shape, -1, np.int32)
+    edge_map[valid] = np.asarray(perm_dst)[bc.edge_map[valid]]
     if edge_to_unique is None:
         edge_map_u = edge_map
     else:
-        e2u = np.asarray(edge_to_unique)
-        edge_map_u = np.where(
-            edge_map >= 0, e2u[np.maximum(edge_map, 0)], -1
-        ).astype(np.int32)
+        edge_map_u = np.full(bc.edge_map.shape, -1, np.int32)
+        edge_map_u[valid] = np.asarray(edge_to_unique)[edge_map[valid]]
     t = bc.num_tiles
     return BlockedCSRDev(
         edge_map=jnp.asarray(edge_map),
@@ -122,6 +124,18 @@ def blocked_csr_dev(
         num_node_blocks=bc.num_node_blocks,
         num_nodes=bc.num_nodes,
     )
+
+
+def msg_rev_dev(msg_rows: np.ndarray, num_rows: int,
+                edge_tile: int,
+                target_edges: Optional[int] = None) -> BlockedCSRDev:
+    """The message-row layout (``layout.msg_rows_csr``) on the device, with
+    canonical edge indices; ``target_edges`` grows it with pad tiles
+    (``layout.pad_blocked_csr``)."""
+    bc, perm = L.msg_rows_csr(msg_rows, num_rows, edge_tile)
+    if target_edges is not None:
+        bc = L.pad_blocked_csr(bc, target_edges)
+    return blocked_csr_dev(bc, perm)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -203,6 +217,85 @@ def unpad_rows(y_p: jnp.ndarray, lay: PaddedSegmentsDev) -> jnp.ndarray:
     Its VJP is ``pad_rows``."""
     _count_perm("unpad")
     return _unpad(y_p, lay.row_map, lay.inv_map)
+
+
+# ---------------------------------------------------------------------------
+# the compact message gather ``msg[msg_rows]`` and its sum by message row
+#
+# ``msg_rows`` repeats rows, so the gather's transpose is a reduction, not a
+# permutation. XLA writes it as a scatter-add into the compact table; here
+# the backward sums on the traversal template instead, over the edges
+# sorted by message row (``msg_rev_dev``).
+# ---------------------------------------------------------------------------
+def _gather_heads(msg: jnp.ndarray, msg_rows: jnp.ndarray,
+                  heads: int) -> jnp.ndarray:
+    """``msg[msg_rows]``; with ``heads`` > 1 the head-major per-edge rows
+    ``_heads_major(msg)[h*Em + msg_rows]`` -> ``[H*E, d/H]``."""
+    if heads == 1:
+        return msg[msg_rows]
+    return _heads_major(msg, heads)[_shift(msg_rows, heads, msg.shape[0])]
+
+
+@functools.lru_cache(maxsize=None)
+def _make_gather_msg_rows(row_block: int, num_row_blocks: int, heads: int,
+                          interpret: bool):
+    @jax.custom_vjp
+    def f(msg, msg_rows, edge_map, local_row, t2b):
+        return _gather_heads(msg, msg_rows, heads)
+
+    def fwd(msg, msg_rows, edge_map, local_row, t2b):
+        shapes = _Static((msg.shape[0], msg_rows.shape))
+        return f(msg, msg_rows, edge_map, local_row, t2b), (
+            edge_map, local_row, t2b, shapes)
+
+    def bwd(res, dy):
+        edge_map, local_row, t2b, shapes = res
+        em, mr = shapes.value
+        dh = dy.shape[-1]
+        # the cotangent in sorted, padded slot order, edges on lanes and
+        # every head's columns stacked: one pass sums all heads of a row
+        dy_h = dy.reshape(heads, mr[0], dh)
+        g_p = jnp.where((edge_map >= 0)[None, :, None],
+                        dy_h[:, jnp.maximum(edge_map, 0)], 0.0)
+        g_p = g_p.transpose(0, 2, 1).reshape(heads * dh, -1)
+        out = TK.seg_row_sum_padded(g_p, local_row, t2b, row_block=row_block,
+                                    num_row_blocks=num_row_blocks,
+                                    interpret=interpret)
+        dmsg = out[:, :em].reshape(heads, dh, em).transpose(2, 0, 1)
+        f0 = jax.dtypes.float0
+        return (dmsg.reshape(em, heads * dh), np.zeros(mr, f0),
+                _float0(edge_map), _float0(local_row), _float0(t2b))
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
+def gather_msg_rows(msg: jnp.ndarray, msg_rows: jnp.ndarray,
+                    rev: Optional[BlockedCSRDev],
+                    backend: Backend = "pallas",
+                    heads: int = 1) -> jnp.ndarray:
+    """``msg[msg_rows]``: compact message rows ``[Em, d]`` -> per edge
+    ``[E, d]``; with ``heads`` the head-major rows ``[H*E, d/H]`` of
+    ``_per_head``. On the Pallas backends with the message-row layout
+    ``rev`` its VJP sums each row's edges, all heads at once, with
+    ``traversal.seg_row_sum_padded``; without it (or on ``xla``) XLA
+    derives its scatter-add."""
+    if rev is None or backend == "xla" or msg_rows.shape[0] == 0:
+        return _gather_heads(msg, msg_rows, heads)
+    f = _make_gather_msg_rows(rev.node_block, rev.num_node_blocks, heads,
+                              backend == "pallas_interpret")
+    return f(msg, msg_rows, rev.edge_map, rev.local_dst, rev.t2b)
+
+
+def _gather_msg(op: str, msg, msg_rows, rev, backend,
+                heads: int = 1) -> jnp.ndarray:
+    """An unfused aggregation's message gather, counted where it is traced
+    by the backward it gets (``path``: ``kernel`` or XLA's ``scatter``)."""
+    if msg_rows is None:
+        return msg if heads == 1 else _heads_major(msg, heads)
+    obs.metrics().counter("msg_row_sum_traced", op=op,
+                          path="scatter" if rev is None else "kernel").inc()
+    return gather_msg_rows(msg, msg_rows, rev, backend, heads)
 
 
 # ---------------------------------------------------------------------------
@@ -617,14 +710,16 @@ def _shift(idx: jnp.ndarray, heads: int, stride: int) -> jnp.ndarray:
 
 
 def _per_head(fn, op: str, scores, msg, dst, num_nodes, bc, backend,
-              msg_rows, msg_slot_map, fuse_gather) -> jnp.ndarray:
+              msg_rows, msg_slot_map, fuse_gather, msg_rev) -> jnp.ndarray:
     """Run the one-head traversal op ``fn`` over ``[E, H]`` score columns
     as one graph of ``H * Np`` destinations (``Np`` the blocked layout's
     padded node count): head ``h`` of node ``v`` is node ``h*Np + v``, of
     edge ``e`` edge ``h*E + e``, of message row ``i`` row ``h*Em + i``
     (its ``h``-th ``d/H`` columns). The blocked layout repeats once per
     head with its node blocks shifted by ``h*Np``, so the kernels and their
-    VJPs run the heads as they run nodes."""
+    VJPs run the heads as they run nodes. An unfused message gather runs
+    here (``gather_msg_rows``), so its backward sums all heads of a message
+    row in one pass over the one-head message-row layout."""
     heads = scores.shape[1]
     _count_heads(op, heads)
     e, em = dst.shape[0], msg.shape[0]
@@ -638,9 +733,15 @@ def _per_head(fn, op: str, scores, msg, dst, num_nodes, bc, backend,
         edge_tile=bc.edge_tile, node_block=bc.node_block,
         num_node_blocks=heads * bc.num_node_blocks,
         num_nodes=heads * np_)
-    rows = None if msg_rows is None else _shift(msg_rows, heads, em)
-    slots = None if msg_slot_map is None else _shift(msg_slot_map, heads, em)
-    out = fn(scores.T.reshape(-1), _heads_major(msg, heads),
+    if fuse_gather:
+        msg_h = _heads_major(msg, heads)
+        rows = None if msg_rows is None else _shift(msg_rows, heads, em)
+        slots = None if msg_slot_map is None else _shift(msg_slot_map,
+                                                        heads, em)
+    else:
+        msg_h = _gather_msg(op, msg, msg_rows, msg_rev, backend, heads)
+        rows = slots = None
+    out = fn(scores.T.reshape(-1), msg_h,
              _shift(dst, heads, np_), heads * np_, bc=bc_h, backend=backend,
              msg_rows=rows, msg_slot_map=slots, fuse_gather=fuse_gather)
     out = out.reshape(heads, np_, -1)[:, :num_nodes]
@@ -666,6 +767,7 @@ def edge_softmax_agg(
     msg_rows: Optional[jnp.ndarray] = None,   # [E] edge -> msg row, or None
     msg_slot_map: Optional[jnp.ndarray] = None,  # [Ep] precomposed slot map
     fuse_gather: bool = True,
+    msg_rev: Optional[BlockedCSRDev] = None,  # message-row layout of msg_rows
 ) -> jnp.ndarray:
     """out[v] = Σ_{e→v} softmax(scores)_e · msg_e — the fused traversal region.
 
@@ -674,7 +776,8 @@ def edge_softmax_agg(
     ``fuse_gather`` (Pallas backends) the per-edge message gather happens
     inside the kernel via the slot map, so no dst-sorted ``[Ep, d]`` copy is
     materialized. ``fuse_gather=False`` keeps the materialized-gather kernel
-    (equivalence baseline).
+    (equivalence baseline); its gather ``msg[msg_rows]`` sums its gradient
+    by message row over ``msg_rev`` (``gather_msg_rows``).
 
     ``[E, H]`` scores are H heads: softmax per column, and head ``h``
     weighs the ``h``-th ``d/H``-wide block of each message. On the Pallas
@@ -689,7 +792,7 @@ def edge_softmax_agg(
                               num_nodes)
         return _per_head(edge_softmax_agg, "softmax_agg", scores, msg, dst,
                          num_nodes, bc, backend, msg_rows, msg_slot_map,
-                         fuse_gather)
+                         fuse_gather, msg_rev)
     if backend == "xla" or bc is None:
         msg_e = msg if msg_rows is None else msg[msg_rows]
         return R.softmax_agg_ref(scores, msg_e, dst, num_nodes)
@@ -705,7 +808,7 @@ def edge_softmax_agg(
                                             interpret)
         return f(scores, msg, dst, rows, bc.edge_map, msg_slot_map,
                  bc.local_dst, bc.t2b)
-    msg_e = msg if msg_rows is None else msg[msg_rows]
+    msg_e = _gather_msg("softmax_agg", msg, msg_rows, msg_rev, backend)
     f = _make_pallas_softmax_agg(bc.node_block, bc.num_node_blocks,
                                  num_nodes, interpret)
     return f(scores, msg_e, dst, bc.edge_map, bc.local_dst, bc.t2b)
@@ -807,6 +910,7 @@ def weighted_agg(
     msg_rows: Optional[jnp.ndarray] = None,
     msg_slot_map: Optional[jnp.ndarray] = None,
     fuse_gather: bool = True,
+    msg_rev: Optional[BlockedCSRDev] = None,
 ) -> jnp.ndarray:
     """out[v] = Σ_{e→v} scale_e · msg_e (gather semantics as edge_softmax_agg;
     an ``[E, H]`` scale weighs each message's H column blocks)."""
@@ -818,7 +922,7 @@ def weighted_agg(
                               num_nodes)
         return _per_head(weighted_agg, "weighted_agg", scale, msg, dst,
                          num_nodes, bc, backend, msg_rows, msg_slot_map,
-                         fuse_gather)
+                         fuse_gather, msg_rev)
     if backend == "xla" or bc is None:
         msg_e = msg if msg_rows is None else msg[msg_rows]
         return R.weighted_agg_ref(scale, msg_e, dst, num_nodes)
@@ -836,7 +940,7 @@ def weighted_agg(
                                              interpret)
         return f(scale, msg, dst, rows, bc.edge_map, msg_slot_map,
                  bc.local_dst, bc.t2b)
-    msg_e = msg if msg_rows is None else msg[msg_rows]
+    msg_e = _gather_msg("weighted_agg", msg, msg_rows, msg_rev, backend)
     f = _make_pallas_weighted_agg(bc.node_block, bc.num_node_blocks,
                                   num_nodes, interpret)
     return f(scale, msg_e, dst, bc.edge_map, bc.local_dst, bc.t2b)

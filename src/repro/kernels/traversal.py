@@ -16,6 +16,10 @@ Kernels (all derived traversal-template instances):
                             (fused edge-softmax + weighted aggregation: the
                             canonical fused traversal region of Listing 1).
 ``seg_weighted_agg_padded`` out[v] = Σ_e scale_e · msg_e (RGCN-style).
+``seg_row_sum_padded``      out[:, i] = Σ_{e reads row i} g[:, e] — the
+                            backward of the compact message gather
+                            ``msg[msg_rows]``, over edges sorted by message
+                            row (``layout.msg_rows_csr``).
 
 The scatter maps onto the MXU: each tile builds a ``[node_block, tile]``
 one-hot of its edges' destinations, scales it by the per-edge weight, and
@@ -358,3 +362,56 @@ def seg_weighted_agg_padded(
         interpret=interpret,
     )(_block_meta(t2b), scale_p.reshape(num_tiles, 1, tile),
       local_dst_p.reshape(num_tiles, 1, tile), msg_p)
+
+
+def _row_sum_kernel(meta_ref, row_ref, g_ref, out_ref, *, row_block):
+    @pl.when(_first_tile(meta_ref))
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    onehot = _onehot(row_ref, row_block).astype(jnp.float32)  # [RB, tile]
+    # exact products of 0/1 and float32 contributions, summed in float32
+    out_ref[...] += jax.lax.dot_general(
+        g_ref[...].astype(jnp.float32), onehot, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("row_block", "num_row_blocks", "interpret")
+)
+def seg_row_sum_padded(
+    g_p: jnp.ndarray,          # [d, T*tile] per-slot rows, edges on lanes
+    local_row_p: jnp.ndarray,  # [T, tile] int32 row - block start (pads: row_block)
+    t2b: jnp.ndarray,          # [T] int32 non-decreasing tile -> row block
+    *,
+    row_block: int,
+    num_row_blocks: int,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Sum slot columns by message row -> ``[d, num_row_blocks * row_block]``.
+
+    Slots are sorted by row and padded so a tile holds one row block's
+    edges; each tile contracts its ``[d, tile]`` columns with the one-hot of
+    their rows into the row block's VMEM output, as the aggregations sum
+    into destination blocks. Edges sit on the lane axis, so a narrow ``d``
+    (11, 32) is not padded to 128 lanes in HBM."""
+    num_tiles, tile = local_row_p.shape
+    d = g_p.shape[0]
+    return pl.pallas_call(
+        functools.partial(_row_sum_kernel, row_block=row_block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(num_tiles,),
+            in_specs=[
+                _tile_spec(tile),
+                pl.BlockSpec((d, tile), lambda t, meta: (0, t)),
+            ],
+            out_specs=pl.BlockSpec((d, row_block),
+                                   lambda t, meta: (0, meta[0, t])),
+        ),
+        out_shape=jax.ShapeDtypeStruct((d, num_row_blocks * row_block),
+                                       g_p.dtype),
+        name="seg_row_sum_padded",
+        interpret=interpret,
+    )(_block_meta(t2b), local_row_p.reshape(num_tiles, 1, tile), g_p)

@@ -95,6 +95,10 @@ KERNELS = {
             s, m, mm, ld, t, **_TRAV),
         [((TILES, TILE), F32), ((SRC_ROWS, D_FEAT), F32), ((ROWS,), I32),
          ((TILES, TILE), I32), ((TILES,), I32)]),
+    "seg_row_sum_padded": (
+        lambda g, lr, t: TK.seg_row_sum_padded(
+            g, lr, t, row_block=4 * NB, num_row_blocks=NODE_BLOCKS // 4),
+        [((11, ROWS), F32), ((TILES, TILE), I32), ((TILES,), I32)]),
     "candidate_keys": (
         lambda s, c: SO.candidate_keys(s, c, 12345, 16, "pallas"),
         [((64, 108), I32), ((64, 108), I32)]),
@@ -123,6 +127,26 @@ def test_segment_mm_backward_compiles_for_narrow_weights(one_chip):
 
     _compile(jax.grad(loss, argnums=(0, 1)), one_chip,
              ((m, D_FEAT), F32), ((GROUPS, D_FEAT, 1), F32))
+
+
+@pytest.mark.parametrize("heads,d", [(1, 11), (1, 64), (8, 32)])
+def test_message_gather_backward_compiles(one_chip, heads, d):
+    """The compact message gather's backward (``ops.gather_msg_rows``) at
+    the cells' narrow widths and RGAT's and HGT's head counts: the slot
+    gather, the row-sum kernel and the transposes back to ``[Em, H*d]``."""
+    rng = np.random.default_rng(0)
+    em, e = 3000, 5000
+    rows = rng.integers(0, em, e).astype(np.int32)
+    rev = ops.msg_rev_dev(rows, em, TILE)
+    idx = jnp.asarray(rows)
+
+    def loss(msg):
+        return jnp.sum(ops.gather_msg_rows(msg, idx, rev, "pallas",
+                                           heads) ** 2)
+
+    text = _compile(jax.grad(loss), one_chip,
+                    ((em, heads * d), F32)).as_text()
+    assert "seg_row_sum_padded" in text
 
 
 def test_fusion_gate_limits_compile(one_chip):

@@ -127,6 +127,37 @@ def test_padding_transposes_are_owned_gemm_gathers(graph, model):
     assert backward == unpadding
 
 
+@pytest.mark.parametrize("model", ["rgat", "hgt_published"])
+def test_message_gather_backward_is_owned_row_sum(graph, monkeypatch, model):
+    """With gather fusion off, as on the cells' full graphs, the transpose
+    of the compact message gather ``msg[msg_rows]`` sums by message row on
+    the traversal template (``ops.gather_msg_rows``), owned by each layer's
+    traversal op, backward. No scatter writes the ``[Em, d]`` message rows
+    (RGAT) or the head-folded ``[H*Em, dh]`` rows (HGT, 4 heads); HGT keeps
+    one ``[Em, H*dh]`` scatter a layer, the transpose of its score gather
+    ``katt[edge_to_unique]``."""
+    import functools
+    from repro.models import hgt_published_program
+    heads = 4 if model == "hgt_published" else 1
+    monkeypatch.setattr(codegen, "_gather_fits", lambda *a, **k: False)
+    text, eng = _compile_train_step(
+        graph, functools.partial(hgt_published_program, heads=heads)
+        if heads > 1 else model)
+    em = int(eng.gt.unique_src.shape[0])
+    instrs = _instructions(text)
+    # feature-wide scatters (RGAT's one-column score gather ``atts`` keeps
+    # its own 1-D scatter into the compact rows)
+    scatters = [_rows(rtype) for _, rtype, opcode, _ in instrs
+                if opcode == "scatter" and "," in rtype.split("]")[0]]
+    assert heads * em not in scatters or heads == 1
+    assert scatters.count(em) == (2 if heads > 1 else 0)
+    owners = {(o.owner.split(".")[0], o.owner.split(".")[1], o.direction)
+              for _, _, _, o in instrs
+              if o is not None and "seg_row_sum_padded" in o.inner}
+    assert owners == {("l0", "traversal", "backward"),
+                      ("l1", "traversal", "backward")}
+
+
 def test_scope_names_never_match_a_kernel_name(graph):
     """The roofline readers match kernel names against instruction names;
     no op scope may carry one."""

@@ -634,3 +634,133 @@ def test_heads_traced_counter(rng):
         assert reg.counter("heads_traced", op="softmax_agg",
                            heads="3").value == 1
         assert reg.counter_total("heads_traced") == 2
+
+
+# ---------------------------------------------------------------------------
+# the compact message gather ``ops.gather_msg_rows`` and its sum by message
+# row over the message-row layout (``layout.msg_rows_csr``)
+def _msg_rows_case(rng, rows, d, n_edges=90, n_rows=40):
+    """(msg [n_rows, d], msg_rows [E], layout) with every row read by 1-4
+    edges (``repeated``), some rows read by none (``unread``), or no edges
+    (``none``)."""
+    if rows == "none":
+        msg_rows = np.zeros(0, np.int32)
+    elif rows == "unread":
+        msg_rows = rng.integers(0, n_rows // 2, n_edges) * 2
+    else:
+        msg_rows = np.concatenate([np.arange(n_rows),
+                                   rng.integers(0, n_rows, n_edges - n_rows)])
+        msg_rows = rng.permutation(msg_rows)
+    msg_rows = msg_rows.astype(np.int32)
+    msg = jnp.asarray(rng.normal(size=(n_rows, d)), jnp.float32)
+    return msg, msg_rows, ops.msg_rev_dev(msg_rows, n_rows, edge_tile=8)
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("d", [6, 11, 32, 64])
+@pytest.mark.parametrize("rows", ["repeated", "unread", "none"])
+def test_gather_msg_rows_matches_plain_gather(rng, backend, d, rows):
+    """The forward is ``msg[msg_rows]`` bit for bit; the gradient equals the
+    scatter-add XLA derives for the plain gather up to float32 rounding
+    (each row's sum runs in another order)."""
+    msg, msg_rows, rev = _msg_rows_case(rng, rows, d)
+    idx = jnp.asarray(msg_rows)
+    c = jnp.asarray(rng.normal(size=(len(msg_rows), d)), jnp.float32)
+    _same_bits(ops.gather_msg_rows(msg, idx, rev, backend), msg[idx])
+
+    def loss(gather):
+        return lambda m: jnp.sum(jnp.sin(gather(m)) * c)
+
+    got = jax.grad(loss(lambda m: ops.gather_msg_rows(m, idx, rev,
+                                                      backend)))(msg)
+    want = jax.grad(loss(lambda m: m[idx]))(msg)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    if rows == "unread":
+        assert not np.any(np.asarray(got)[1::2])
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("op", sorted(_TRAVERSALS))
+@pytest.mark.parametrize("heads", [1, 8])
+def test_traversal_msg_rev_matches_ref(rng, backend, op, heads):
+    """Unfused aggregations of compact messages with the message-row layout
+    (one head, and 8 heads whose gather runs on whole rows), forward and VJP
+    against ``kernels/ref.py``, and against the same op whose message
+    gradient XLA scatters."""
+    fn, ref = _TRAVERSALS[op]
+    dst, bc, scores, msg, rows = _heads_traversal_case(rng, heads, True)
+    rev = ops.msg_rev_dev(np.asarray(rows), msg.shape[0], edge_tile=8)
+    scores = scores if heads > 1 else scores[:, 0]
+
+    def f(rev):
+        return lambda s, m: fn(s, m, dst, 13, bc=bc, backend=backend,
+                               msg_rows=rows, fuse_gather=False, msg_rev=rev)
+
+    def f_ref(s, m):
+        s = s if heads > 1 else s[:, None]
+        return _per_head_ref(ref, s, m, rows, dst, 13)
+
+    np.testing.assert_allclose(f(rev)(scores, msg), f_ref(scores, msg),
+                               rtol=1e-5, atol=1e-5)
+
+    def grads(fn_):
+        return jax.grad(lambda *a: jnp.sum(jnp.cos(fn_(*a))),
+                        argnums=(0, 1))(scores, msg)
+
+    got = grads(f(rev))
+    for want, tol in ((grads(f_ref), 1e-4), (grads(f(None)), 1e-5)):
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(a, b, rtol=tol, atol=tol)
+
+
+def test_msg_row_sum_traced_counter(rng):
+    """One count per unfused compact message gather traced, by op and by
+    the backward it gets; none for a gather-fused or vanilla message."""
+    from repro import obs
+    dst, bc, scores, msg, rows = _heads_traversal_case(rng, 1, True)
+    rev = ops.msg_rev_dev(np.asarray(rows), msg.shape[0], edge_tile=8)
+    kw = dict(bc=bc, backend="pallas_interpret", msg_rows=rows)
+    with obs.scope() as st:
+        f = jax.jit(lambda s, m: ops.edge_softmax_agg(
+            s, m, dst, 13, fuse_gather=False, msg_rev=rev, **kw))
+        f(scores[:, 0], msg)
+        f(scores[:, 0], msg)
+        ops.weighted_agg(scores[:, 0], msg, dst, 13, fuse_gather=False, **kw)
+        ops.weighted_agg(scores[:, 0], msg, dst, 13, fuse_gather=True, **kw)
+        reg = st.registry
+        assert reg.counter("msg_row_sum_traced", op="softmax_agg",
+                           path="kernel").value == 1
+        assert reg.counter("msg_row_sum_traced", op="weighted_agg",
+                           path="scatter").value == 1
+        assert reg.counter_total("msg_row_sum_traced") == 2
+
+
+@pytest.mark.parametrize("graph", ["am", "bgs", "unread", "none"])
+def test_msg_rows_layout_invariant(graph):
+    """Every edge sits in one slot of the message-row layout, slots run
+    through rows in order within each row's block, pads are -1, and on the
+    am- and bgs-shaped graphs the padded slots stay within 1.15 edges."""
+    from repro.core.graph import CPU_REDUCED_SCALES, table3_graph
+    tile = 128
+    if graph in ("am", "bgs"):
+        hg = table3_graph(graph, scale=CPU_REDUCED_SCALES[graph])
+        rows, n_rows = hg.edge_to_unique, hg.num_unique
+    else:
+        n_rows = 300
+        rows = (np.arange(0, n_rows, 3).repeat(2) if graph == "unread"
+                else np.zeros(0)).astype(np.int32)
+    bc, perm = L.msg_rows_csr(rows, n_rows, tile)
+    lay = ops.blocked_csr_dev(bc, perm)
+    edge_map = np.asarray(lay.edge_map)
+    e = len(rows)
+    assert np.array_equal(np.sort(edge_map[edge_map >= 0]), np.arange(e))
+    pads = edge_map < 0
+    assert np.all(bc.local_dst[pads] == bc.node_block)
+    row = (np.repeat(bc.tile_to_block, tile) * bc.node_block
+           + bc.local_dst)[~pads]
+    assert np.array_equal(row, rows[edge_map[~pads]])
+    assert np.all(np.diff(row) >= 0)
+    # every row block owns a tile, so every output block is zeroed
+    assert set(bc.tile_to_block) == set(range(bc.num_node_blocks))
+    if graph in ("am", "bgs"):
+        assert bc.padded_edges <= 1.15 * e, bc.padded_edges / e
