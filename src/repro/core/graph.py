@@ -332,7 +332,7 @@ def synthetic_heterograph(
 
 
 # Published statistics of the paper's Table 3 datasets (post DGL/OGB
-# preprocessing). Used by benchmarks with a scale factor for CPU tractability.
+# preprocessing). Used with a scale factor for CPU tractability.
 TABLE3_DATASETS = {
     # name: (num_nodes, num_ntypes, num_edges, num_etypes)
     "aifb":    (7_300,     7,  49_000,   104),
@@ -354,8 +354,8 @@ TABLE3_COMPACTION = {
 }
 
 
-# CPU-tractable scale factors (statistics proportional) shared by the
-# benchmarks and the --reduced serving mode, so both run the same graphs.
+# CPU-tractable scale factors (statistics proportional) of the --reduced
+# serving mode and the tests that run its graphs.
 CPU_REDUCED_SCALES = {
     "aifb": 0.5, "mutag": 0.2, "bgs": 0.03, "fb15k": 0.03,
     "biokg": 0.005, "am": 0.004, "mag": 0.001, "wikikg2": 0.001,

@@ -466,7 +466,7 @@ class ModelSpec:
     def definition_loc(self) -> int:
         """Non-blank, non-comment source lines of the model definition
         (decorator line excluded) — the paper's §4.1 programming-effort
-        metric, reported by ``benchmarks/loc_report.py``."""
+        metric, held to the paper's budget by ``tests/test_frontend.py``."""
         src = inspect.getsource(self.fn)
         return sum(1 for line in src.splitlines()
                    if line.strip() and not line.strip().startswith(("#", "@")))

@@ -1,8 +1,7 @@
-"""Production meshes + elastic re-mesh planning.
+"""Device meshes + elastic re-mesh planning.
 
-``make_production_mesh`` is a FUNCTION (importing this module never touches
-jax device state). Single pod = 16x16 = 256 chips ("data","model");
-multi-pod = 2x16x16 = 512 chips ("pod","data","model").
+The builders are functions: importing this module never touches jax
+device state.
 
 ``plan_elastic_mesh`` supports fault tolerance: given the number of
 *surviving* devices after failures, pick the largest factorizable mesh that
@@ -15,12 +14,6 @@ import dataclasses
 from typing import Optional, Sequence, Tuple
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str],
@@ -49,12 +42,11 @@ def make_data_mesh(num_devices: Optional[int] = None,
                    devices: Optional[Sequence] = None):
     """1-D data-only mesh (axis ``"data"``) over any device count.
 
-    Unlike ``make_production_mesh`` this makes no 16-wide-TP or
-    pod-topology assumption: it works on whatever ``jax.devices()``
-    provides — including CPU hosts forced to N devices via
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` — and accepts an
-    explicit (non-contiguous, e.g. post-failure surviving) device list.
-    ``num_devices=None`` uses every available device.
+    It makes no tensor-parallel or pod-topology assumption: it works on
+    whatever ``jax.devices()`` provides — including CPU hosts forced to N
+    devices via ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` —
+    and accepts an explicit (non-contiguous, e.g. post-failure surviving)
+    device list. ``num_devices=None`` uses every available device.
     """
     if devices is None:
         devices = jax.devices()
@@ -89,8 +81,8 @@ def plan_elastic_mesh(surviving: int, model_parallel: int = 16,
     partitioned-graph executors in ``dist/``, which have no TP axis at
     all): every survivor is usable and logical graph shards refold onto
     the remaining devices (``shards_per_device = P // dp``). The default
-    keeps the trailing ``"model"`` axis even at ``model_parallel=1`` —
-    the LM partitioner resolves specs against that axis by name.
+    keeps the trailing ``"model"`` axis even at ``model_parallel=1``, so
+    a tensor-parallel sharding can resolve specs against it by name.
     """
     if surviving < model_parallel:
         raise ValueError(

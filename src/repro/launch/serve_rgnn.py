@@ -349,7 +349,7 @@ def _serve_dist(engine, graph, store, params, batch_size, num_batches,
     """Multi-shard serving loop: route each request batch to its owner
     shards, sample per shard, run the one compiled ``shard_map`` step,
     report request-order predictions. Stats keys mirror the single-box
-    loop so benchmarks/tests compare the two paths directly.
+    loop so tests compare the two paths directly.
 
     The per-owner feature slabs are read through the feature store
     (``host_rows``), so with a host/cached store the full table never

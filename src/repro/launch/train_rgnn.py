@@ -112,8 +112,8 @@ def train(
     profile: bool = False,
     log=print,
 ):
-    """Run the sampled training loop; returns a stats dict (used by tests
-    and the ``train_sampled`` benchmark).
+    """Run the sampled training loop; returns a stats dict (used by the
+    tests).
 
     Observability mirrors ``serve_rgnn``: with ``obs_mode="on"`` the run is
     wrapped in an ``obs.scope`` (per-step latency histograms, cache/trace

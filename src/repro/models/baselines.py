@@ -1,8 +1,8 @@
 """Vanilla baseline implementations (the systems Hector is compared against).
 
-These reproduce the inefficiencies the paper profiles in §2.3 / Fig. 4 so the
-fig8/table5 benchmarks have a faithful comparison point **with identical
-numerics** (same parameter pytrees as the HectorModule plans):
+These reproduce the inefficiencies the paper profiles in §2.3 / Fig. 4, and
+are the tests' reference for the generated code **with identical numerics**
+(same parameter pytrees as the HectorModule plans):
 
 * ``typed_linear_replicated``  — materializes the [E, d_in, d_out] per-edge
   weight tensor (PyG FastRGCNConv / bmm pattern): the "huge temporary weight

@@ -6,8 +6,8 @@ Formula (paper Eq. 1):
 We use the in-degree normalizer (DGL's default 'right' norm) folded into the
 mean-reduce of the aggregation. The traced program is statement-for-statement
 identical to the hand-assembled IR this module used to build (pinned by
-tests/test_frontend.py); the paper's 51-LoC-for-3-models data point is
-reproduced in benchmarks/loc_report.py.
+tests/test_frontend.py, which also holds the three paper models to the
+paper's 51-LoC-for-3-models data point).
 """
 from repro import frontend as hector
 from repro.core.ir import inter_op as I

@@ -31,8 +31,8 @@ Drivers opt in with a scope::
         sc.registry.export("metrics.json")
 
 Scopes nest; on exit a scope folds its counters/histograms/spans into the
-enclosing enabled scope (so ``benchmarks/run.py`` sees the union of every
-benchmark's metrics while each ``serve()`` call keeps exact local counts).
+enclosing enabled scope (so an outer scope sees the union of every
+run's metrics while each ``serve()`` call keeps exact local counts).
 """
 from __future__ import annotations
 

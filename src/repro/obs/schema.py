@@ -9,9 +9,9 @@ documents the obs layer exports:
 * **Metrics snapshot** (``MetricsRegistry.export``): the
   ``schema_version``-stamped counters/gauges/histograms document.
 
-``validate_*`` return a list of problem strings (empty = valid) so CI gates
-(``benchmarks/obs_smoke.py``) can print every violation at once instead of
-failing on the first.
+``validate_*`` return a list of problem strings (empty = valid) so a
+check (``tests/test_gate_obs_smoke.py``) can report every violation at
+once instead of failing on the first.
 """
 from __future__ import annotations
 

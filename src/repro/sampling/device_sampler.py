@@ -180,7 +180,7 @@ class DeviceSampler:
         guesses. Non-blocking by default — only counts already resident on
         the host (``is_ready``) are read, so the sampling loop stays
         sync-free. ``block=True`` waits for everything outstanding (a
-        warmup barrier for benchmarks/tests; each forced wait counts as a
+        warmup barrier for tests; each forced wait counts as a
         ``count_syncs`` readback)."""
         while self._pending:
             sig, fp, used, counts = self._pending[0]

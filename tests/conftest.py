@@ -7,8 +7,8 @@ import pytest
 
 import jax
 
-# NOTE: no XLA_FLAGS here — smoke tests and benches must see ONE device.
-# Multi-device tests spawn subprocesses (test_distributed.py).
+# NOTE: no XLA_FLAGS here — the tests must see ONE device. Multi-device
+# tests spawn subprocesses (``run_sub`` in test_dist.py).
 
 jax.config.update("jax_enable_x64", False)
 

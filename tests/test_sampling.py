@@ -324,11 +324,35 @@ def test_loader_block_cache_epoch_keyed_for_training_streams(graph):
     cached blocks — destroying neighbor-sampling stochasticity. With an
     epoch-aware seed source the key (and the sampler rng) includes the
     epoch: same seeds, later epoch -> fresh sample, zero cache hits."""
-    from benchmarks.train_sampled import check_fresh_blocks_per_epoch
+    train_seeds = np.arange(32, dtype=np.int32)
 
-    failures = []
-    check_fresh_blocks_per_epoch(failures)   # shared with the CI gate
-    assert failures == []
+    def edge_key(mb):
+        b = mb.seq.blocks[0]
+        return (b.node_ids[b.graph.src].tobytes(),
+                b.node_ids[b.graph.dst].tobytes())
+
+    class ConstantEpochStream:
+        """Same seed batch every step; one step per 'epoch'."""
+        def batch(self, step):
+            return train_seeds
+
+        def epoch_of(self, step):
+            return step
+
+    loader = MiniBatchLoader(FanoutSampler(graph, [3, 3], seed=0),
+                             ConstantEpochStream(), tile=8, node_block=8,
+                             bucket=True, num_batches=3, cache_blocks=8)
+    try:
+        keys = [edge_key(mb) for mb in loader]
+        cache = loader.block_cache.stats()
+    finally:
+        loader.close()
+    assert len(set(keys)) == len(keys), (
+        "same seed batch replayed identical blocks across epochs "
+        "(block cache is not epoch-keyed)")
+    assert cache["hits"] == 0, (
+        f"{cache['hits']} block-cache hits across epochs for a training "
+        f"stream (expected 0: fresh sample each epoch)")
 
     # serving streams (no epoch_of) keep the replay semantics: same seeds
     # at a later step return the cached block
